@@ -28,7 +28,8 @@ cross-worker collective edges (ring / hierarchical / fused) and returns a
 per-worker :class:`SimResult` breakdown — see :mod:`repro.core.cluster`.
 """
 
-from .task import (Task, TaskKind, HardwareSpec, TPU_V5E, HOST_THREAD,
+from .task import (Task, TaskKind, HardwareSpec, TPU_V5E, PEAKS,
+                   hardware_for, HOST_THREAD,
                    DEVICE_STREAM, DATA_THREAD, DMA_CHANNEL, ici_channel,
                    p2p_channel, worker_thread, split_worker_thread)
 from .graph import DependencyGraph, GraphError
@@ -55,7 +56,7 @@ from . import optimize
 from . import whatif
 
 __all__ = [
-    "Task", "TaskKind", "HardwareSpec", "TPU_V5E",
+    "Task", "TaskKind", "HardwareSpec", "TPU_V5E", "PEAKS", "hardware_for",
     "HOST_THREAD", "DEVICE_STREAM", "DATA_THREAD", "DMA_CHANNEL", "ici_channel",
     "p2p_channel", "worker_thread", "split_worker_thread",
     "DependencyGraph", "GraphError",

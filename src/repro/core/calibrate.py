@@ -1,7 +1,7 @@
-"""CPU calibration for measured-mode validation (DESIGN.md §4).
+"""Local-backend calibration for measured-mode validation (DESIGN.md §4).
 
-The container has no TPU; validating Daydream's *methodology* (predict ->
-implement -> compare, paper §6) therefore runs on the CPU backend.  This module
+Daydream's *methodology* (predict -> implement -> compare, paper §6) is
+validated against whatever backend the process runs on.  This module
 measures the local backend's effective matmul FLOP/s, element-wise memory
 bandwidth, and (multi-host-device) collective bandwidth, producing a
 :class:`repro.core.costmodel.CostModel` whose analytical durations are in local
@@ -71,8 +71,8 @@ def measure_collective_bandwidth(num_devices: Optional[int] = None,
     if n < 2:
         return 8e9
     from jax.sharding import PartitionSpec as P, NamedSharding
-    from repro import compat
-    mesh = compat.make_mesh((n,), ("d",))
+    mesh = jax.make_mesh((n,), ("d",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     elems = payload_mb * 1024 * 1024 // 4
     x = jnp.ones((n, elems), jnp.float32)
     x = jax.device_put(x, NamedSharding(mesh, P("d", None)))
@@ -124,8 +124,8 @@ def measure_collective_hop_latency(num_devices: Optional[int] = None,
     bw = bandwidth if bandwidth is not None \
         else measure_collective_bandwidth(n)
     from jax.sharding import PartitionSpec as P, NamedSharding
-    from repro import compat
-    mesh = compat.make_mesh((n,), ("d",))
+    mesh = jax.make_mesh((n,), ("d",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     elems = max(payload_kb * 1024 // 4, 1)
     x = jnp.ones((n, elems), jnp.float32)
     x = jax.device_put(x, NamedSharding(mesh, P("d", None)))
@@ -144,7 +144,7 @@ def calibrated_cost_model(num_devices: int = 1) -> CostModel:
     else:
         coll_bw, hop = 8e9, CollectiveModel.HOP_LATENCY
     hw = HardwareSpec(
-        name="local-cpu",
+        name=jax.devices()[0].device_kind,
         peak_flops=m["matmul_flops_per_s"],
         hbm_bandwidth=m["elementwise_bytes_per_s"],
         ici_bandwidth=coll_bw,

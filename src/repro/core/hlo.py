@@ -87,9 +87,8 @@ _MEMORY_OPS = {
     "copy-from-host",
 }
 
-_INSTR_RE = re.compile(
-    r"^\s*(ROOT\s+)?%([\w\.\-]+)\s*=\s*((?:\([^()]*\)|[a-z][\w\[\]\{\},\s]*?))\s+"
-    r"([\w\-]+)\(")
+_INSTR_HEAD_RE = re.compile(r"^\s*(ROOT\s+)?%([\w\.\-]+)\s*=\s*")
+_OPCODE_RE = re.compile(r"\s+([\w\-]+)\(")
 _METADATA_RE = re.compile(r'op_name="([^"]*)"')
 _CALLS_RE = re.compile(r"(?:calls|to_apply|body)=%([\w\.\-]+)")
 _COND_RE = re.compile(r"condition=%([\w\.\-]+)")
@@ -97,6 +96,8 @@ _BRANCHES_RE = re.compile(r"branch_computations=\{([^}]*)\}")
 _TRIP_RE = re.compile(r'"known_trip_count":\{"n":"(\d+)"\}')
 _IOTA_RG_RE = re.compile(r"replica_groups=\[([\d,]+)\]<=\[([\d,]+)\](?:T\(([\d,]+)\))?")
 _EXPLICIT_RG_RE = re.compile(r"replica_groups=\{(\{[^=]*?\})\}")
+_CONST_RE = re.compile(r"\sconstant\((\d+)\)")
+_DIM_LABELS_RE = re.compile(r"dim_labels=\w+_(\w+)->")
 _CONTRACT_RE = re.compile(r"lhs_contracting_dims=\{([\d,]*)\}")
 _BATCH_RE = re.compile(r"lhs_batch_dims=\{([\d,]*)\}")
 
@@ -131,10 +132,6 @@ class HloInstr:
         if not m:
             return []
         return [b.strip().lstrip("%") for b in m.group(1).split(",")]
-
-    def trip_count(self) -> Optional[int]:
-        m = _TRIP_RE.search(self.raw)
-        return int(m.group(1)) if m else None
 
     def replica_groups(self) -> Optional[np.ndarray]:
         """Return (num_groups, group_size) array of device ids, or None."""
@@ -177,6 +174,46 @@ class HloModule:
     def entry_computation(self) -> HloComputation:
         return self.computations[self.entry]
 
+    def trip_count(self, instr: HloInstr) -> Optional[int]:
+        """A ``while`` loop's trip count: ``known_trip_count`` where the
+        compiler records it (CPU), else the bound of a condition of the
+        form ``induction_var < constant`` (TPU, which drops the record)."""
+        m = _TRIP_RE.search(instr.raw)
+        if m:
+            return int(m.group(1))
+        comp = self.computations.get(instr.cond() or "")
+        if comp is None:
+            return None
+        root = next((i for i in comp.instrs if i.is_root), None)
+        if root is None or root.opcode != "compare" \
+                or "direction=LT" not in root.raw:
+            return None
+        by_name = comp.by_name()
+        for o in root.operands[1:]:
+            c = _CONST_RE.search(by_name[o].raw) if o in by_name else None
+            if c:
+                return int(c.group(1))
+        return None
+
+
+def _type_end(line: str, i: int) -> int:
+    """Index just past the HLO type starting at ``line[i]``: a balanced
+    tuple ``(...)``, or one array type up to the next top-level space.
+    Brackets are balanced because TPU layouts nest parentheses inside
+    braces (``bf16[2,2048]{1,0:T(8,128)(2,1)}``)."""
+    depth = 0
+    for j in range(i, len(line)):
+        ch = line[j]
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            depth -= 1
+            if depth == 0 and line[i] == "(":
+                return j + 1
+        elif ch.isspace() and depth == 0:
+            return j
+    return len(line)
+
 
 _COMP_HDR_RE = re.compile(r"^(ENTRY\s+)?%?([\w\.\-]+)\s*\(.*\)\s*->.*\{\s*$")
 
@@ -202,13 +239,18 @@ def parse_hlo_module(text: str) -> HloModule:
             computations[cur.name] = cur
             cur = None
             continue
-        im = _INSTR_RE.match(line)
-        if not im:
+        head = _INSTR_HEAD_RE.match(line)
+        if not head:
+            continue
+        type_end = _type_end(line, head.end())
+        om = _OPCODE_RE.match(line, type_end)
+        if not om:
             continue
         is_root, name, type_str, opcode = (
-            bool(im.group(1)), im.group(2), im.group(3).strip(), im.group(4))
+            bool(head.group(1)), head.group(2),
+            line[head.end():type_end].strip(), om.group(1))
         # operands: %tokens inside the first balanced paren group after opcode
-        rest = line[im.end() - 1:]
+        rest = line[om.end() - 1:]
         depth = 0
         end = 0
         for i, ch in enumerate(rest):
@@ -251,14 +293,28 @@ def _operand_bytes(instr: HloInstr, operand_types: Dict[str, str]) -> float:
 
 
 def _conv_flops(instr: HloInstr, operand_types: Dict[str, str]) -> float:
-    # rough: 2 * out_elems * kernel_elems / out_channels
+    """2 * out_elems * kernel_elems / out_channels.
+
+    The kernel's output feature dim is the ``o`` of ``dim_labels``, else
+    its last dim.  TPU lowers every dot to a convolution and folds batch
+    dims of a batched dot into window dims that a dilation or reversal
+    lines up one-to-one with the output (``lhs_dilate=2x4x1``); such a
+    window contracts nothing, so only the input feature dim ``i`` counts.
+    """
     out = instr.out_elems
-    if len(instr.operands) >= 2:
-        k = _shape_elems(operand_types.get(instr.operands[1], ""))
-        kd = _first_dims(operand_types.get(instr.operands[1], ""))
+    if len(instr.operands) < 2:
+        return 2.0 * out
+    kd = _first_dims(operand_types.get(instr.operands[1], ""))
+    labels = _DIM_LABELS_RE.search(instr.raw)
+    if labels and len(labels.group(1)) == len(kd):
+        lab = labels.group(1)
+        if "lhs_dilate=" in instr.raw or "rhs_reversal=" in instr.raw:
+            return 2.0 * out * kd[lab.index("i")]
+        oc = kd[lab.index("o")]
+    else:
         oc = kd[-1] if kd else 1
-        return 2.0 * out * max(k / max(oc, 1), 1.0)
-    return 2.0 * out
+    k = _shape_elems(operand_types.get(instr.operands[1], ""))
+    return 2.0 * out * max(k / max(oc, 1), 1.0)
 
 
 class _CostVisitor:
@@ -472,7 +528,7 @@ def aggregate_costs(module: HloModule, cost: Optional[CostModel] = None,
         types = {i.name: i.type_str for i in comp.instrs}
         for instr in comp.instrs:
             if instr.opcode == "while":
-                n = instr.trip_count() or 1
+                n = module.trip_count(instr) or 1
                 for body in instr.called():
                     walk(body, mult * n, depth + 1)
                 continue
@@ -551,7 +607,7 @@ def extract_graph(module: HloModule, cost: Optional[CostModel] = None,
 
         for instr in comp.instrs:
             if instr.opcode == "while":
-                n = instr.trip_count() or 1
+                n = module.trip_count(instr) or 1
                 bodies = instr.called()
                 body = bodies[0] if bodies else None
                 if body is None:

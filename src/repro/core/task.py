@@ -211,3 +211,19 @@ class HardwareSpec:
 
 
 TPU_V5E = HardwareSpec()
+
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# TPU v5e: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+# 16 GB HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect
+# (four 50 GB/s links).
+PEAKS: Dict[str, HardwareSpec] = {"TPU v5 lite": TPU_V5E}
+
+
+def hardware_for(device_kind: str) -> HardwareSpec:
+    """The peaks of a device kind; a kind not in :data:`PEAKS` is an error,
+    not a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None

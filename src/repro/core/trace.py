@@ -46,8 +46,7 @@ class TraceBundle:
     def xla_cost_analysis(self) -> Dict[str, float]:
         if self.compiled is None:
             return {}
-        from repro.compat import cost_analysis_dict
-        return cost_analysis_dict(self.compiled)
+        return self.compiled.cost_analysis()
 
     def memory_analysis(self):
         if self.compiled is None:

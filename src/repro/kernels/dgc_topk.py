@@ -33,7 +33,7 @@ def _dgc_kernel(g_ref, thr_ref, o_ref, cnt_ref):
 
 
 def dgc_threshold_2d(g: jax.Array, thr: jax.Array, *,
-                     interpret: bool = True):
+                     interpret: bool):
     """g: (rows, LANE) f32; thr: (1,) f32 -> (sparse g, per-row keep counts)."""
     rows = g.shape[0]
     blk = min(BLOCK_ROWS, rows)

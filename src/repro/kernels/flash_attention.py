@@ -87,13 +87,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     block_k: int = DEFAULT_BLOCK_K,
                     sm_scale: float = 0.0,
                     kv_len: int = 0,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """q: (B, H, S, D); k/v: (B, KH, S, D) -> (B, H, S, D).
 
     D must be a multiple of 128 and S a multiple of the block sizes (the ops
     wrapper pads; ``sm_scale``/``kv_len`` carry the pre-padding softmax scale
     and valid key count).  ``interpret=True`` executes the kernel body in
-    Python on CPU (the validation mode here); on a real TPU pass False.
+    Python on CPU; ``False`` compiles it for the TPU.
     """
     B, H, S, D = q.shape
     KH = k.shape[1]

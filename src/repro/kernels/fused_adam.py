@@ -44,7 +44,7 @@ def _adam_kernel(p_ref, g_ref, m_ref, v_ref, lr_ref, c1_ref, c2_ref,
 def fused_adam_2d(p: jax.Array, g: jax.Array, m: jax.Array, v: jax.Array,
                   lr: jax.Array, c1: jax.Array, c2: jax.Array, *,
                   b1: float = 0.9, b2: float = 0.95, eps: float = 1e-8,
-                  wd: float = 0.1, interpret: bool = True):
+                  wd: float = 0.1, interpret: bool):
     """All arrays (rows, LANE) f32; lr/c1/c2 shape-(1,) f32 scalars."""
     rows = p.shape[0]
     blk = min(BLOCK_ROWS, rows)

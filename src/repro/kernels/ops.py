@@ -1,6 +1,7 @@
 """Jit'd public wrappers for the Pallas kernels: padding, reshaping, dtype
-management.  ``interpret`` defaults to True (this container validates kernels
-via the Pallas interpreter); a TPU deployment flips ``set_interpret(False)``.
+management.  Each wrapper runs its kernel through the Pallas interpreter on
+the CPU backend and compiles it for the device on every other backend; the
+choice is made from ``jax.default_backend()`` when the wrapper is traced.
 """
 
 from __future__ import annotations
@@ -16,12 +17,9 @@ from . import fused_adam as _ad
 from . import rmsnorm as _rn
 from . import dgc_topk as _dg
 
-_INTERPRET = True
 
-
-def set_interpret(flag: bool) -> None:
-    global _INTERPRET
-    _INTERPRET = flag
+def _interpret() -> bool:
+    return jax.default_backend() == "cpu"
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> Tuple[jax.Array, int]:
@@ -54,7 +52,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     # padded key positions are masked via kv_len; scale uses the real D
     out = _fa.flash_attention(qp, kp, vp, causal=causal, block_q=bq,
                               block_k=bk, sm_scale=1.0 / math.sqrt(D),
-                              kv_len=S, interpret=_INTERPRET)
+                              kv_len=S, interpret=_interpret())
     return out[:, :, :S, :D]
 
 
@@ -82,7 +80,7 @@ def fused_adam(p: jax.Array, g: jax.Array, m: jax.Array, v: jax.Array, *,
         jnp.asarray(lr, jnp.float32).reshape(1),
         jnp.asarray(c1, jnp.float32).reshape(1),
         jnp.asarray(c2, jnp.float32).reshape(1),
-        b1=b1, b2=b2, eps=eps, wd=wd, interpret=_INTERPRET)
+        b1=b1, b2=b2, eps=eps, wd=wd, interpret=_interpret())
     return (po.reshape(-1)[:N], mo.reshape(-1)[:N], vo.reshape(-1)[:N])
 
 
@@ -102,7 +100,7 @@ def rmsnorm(x: jax.Array, w: jax.Array, eps: float = 1e-6) -> jax.Array:
         padr = blk - rows % blk
         x2p = jnp.concatenate(
             [x2p, jnp.zeros((padr, x2p.shape[1]), x2p.dtype)])
-    out = _rn.rmsnorm_2d(x2p, wp, eps=eps, d_real=D, interpret=_INTERPRET)
+    out = _rn.rmsnorm_2d(x2p, wp, eps=eps, d_real=D, interpret=_interpret())
     if padr:
         out = out[:-padr]
     return out[:, :D].reshape(shape)
@@ -126,7 +124,7 @@ def dgc_mask(g: jax.Array, threshold: jax.Array):
         g2 = jnp.concatenate([g2, jnp.zeros((padr, lane), jnp.float32)])
     out, cnt = _dg.dgc_threshold_2d(
         g2, jnp.asarray(threshold, jnp.float32).reshape(1),
-        interpret=_INTERPRET)
+        interpret=_interpret())
     if padr:
         out, cnt = out[:-padr], cnt[:-padr]
     sparse = out.reshape(-1)[:N].reshape(shape).astype(g.dtype)

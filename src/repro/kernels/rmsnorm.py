@@ -34,7 +34,7 @@ def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float, d_real: int):
 
 
 def rmsnorm_2d(x: jax.Array, w: jax.Array, *, eps: float = 1e-6,
-               d_real: int = 0, interpret: bool = True) -> jax.Array:
+               d_real: int = 0, interpret: bool) -> jax.Array:
     rows, D = x.shape
     blk = min(BLOCK_ROWS, rows)
     grid = (rows // blk,)

@@ -62,16 +62,14 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     topo = mesh_topology(multi_pod)
     cost = CostModel(topo=topo)
     try:
-        from repro import compat
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             cell = build_cell(cfg, shape, mesh, rules)
             lowered = cell.lower()
             t_lower = time.time() - t0
             compiled = lowered.compile()
             t_compile = time.time() - t0 - t_lower
             mem = compiled.memory_analysis()
-            from repro.compat import cost_analysis_dict
-            xla_cost = cost_analysis_dict(compiled)
+            xla_cost = compiled.cost_analysis()
             module = parse_hlo_module(compiled.as_text())
             agg = aggregate_costs(module, cost,
                                   devices_per_pod=DEVICES_PER_POD)

@@ -53,13 +53,13 @@ def search_whatif(args, cfg) -> None:
     # lazy: perf_report imports this module at top level (parse_value)
     from repro.launch.perf_report import build_scenario
     from repro.configs import registry as cfg_registry
-    from repro import compat
+    import jax
 
     shape = cfg_registry.SHAPES[args.shape]
     multi = args.mesh == "multi"
     mesh = make_production_mesh(multi_pod=multi)
     cost = CostModel(topo=mesh_topology(multi))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         cell = build_cell(cfg, shape, mesh)
         compiled = cell.lower().compile()
     module = parse_hlo_module(compiled.as_text())

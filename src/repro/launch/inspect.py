@@ -30,8 +30,7 @@ def rank_cell(arch: str, shape_name: str, multi_pod: bool = False,
     shape = registry.SHAPES[shape_name]
     mesh = make_production_mesh(multi_pod=multi_pod)
     cost = CostModel(topo=mesh_topology(multi_pod))
-    from repro import compat
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         cell = build_cell(cfg, shape, mesh,
                           ShardingRules(layout=layout))
         compiled = cell.lower().compile()
@@ -46,7 +45,7 @@ def rank_cell(arch: str, shape_name: str, multi_pod: bool = False,
         types = {i.name: i.type_str for i in c.instrs}
         for i in c.instrs:
             if i.opcode == "while":
-                n = i.trip_count() or 1
+                n = module.trip_count(i) or 1
                 for b in i.called():
                     walk(b, mult * n, depth + 1)
                 continue

@@ -36,10 +36,9 @@ def make_mesh(shape: Sequence[int], axes: Sequence[str]):
         raise RuntimeError(
             f"mesh {tuple(shape)} needs {n} devices, have {len(devs)} "
             f"(dry-run requires XLA_FLAGS=--xla_force_host_platform_device_count)")
-    from repro import compat
-    return compat.make_mesh(
+    return jax.make_mesh(
         tuple(shape), tuple(axes),
-        devices=devs[:n] if len(devs) != n else None)
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes), devices=devs[:n])
 
 
 def smoke_mesh(model: int = 2, data: Optional[int] = None):

@@ -62,7 +62,7 @@ def aggregate_with_attention_split(module, cost):
         types = {i.name: i.type_str for i in c.instrs}
         for i in c.instrs:
             if i.opcode == "while":
-                n = i.trip_count() or 1
+                n = module.trip_count(i) or 1
                 inner = in_attn or bool(_ATTN_SCOPE.search(i.op_name or ""))
                 for b in i.called():
                     walk(b, mult * n, inner, depth + 1)
@@ -514,8 +514,7 @@ def main() -> None:
     chips = 512 if multi else 256
     mesh = make_production_mesh(multi_pod=multi)
     cost = CostModel(topo=mesh_topology(multi))
-    from repro import compat
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         cell = build_cell(cfg, shape, mesh)
         compiled = cell.lower().compile()
     module = parse_hlo_module(compiled.as_text())

@@ -18,6 +18,7 @@ import jax
 from repro.configs import get_config, get_smoke_config
 from repro.data import SyntheticLM, make_batch, Prefetcher
 from repro.optim import AdamW, warmup_cosine
+from repro.runtime.compile_cache import use_compile_cache
 from repro.train import Trainer, TrainerConfig
 
 
@@ -34,6 +35,7 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--metrics-out", default=None)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     opt = AdamW(lr=warmup_cosine(args.lr, args.steps // 10, args.steps))
@@ -53,8 +55,7 @@ def main() -> None:
             step += 1
 
     trainer = Trainer(cfg, tc, optimizer=opt, mesh=mesh)
-    from repro import compat
-    ctx = compat.set_mesh(mesh) if mesh is not None else _null()
+    ctx = jax.set_mesh(mesh) if mesh is not None else _null()
     with ctx:
         trainer.fit(Prefetcher(batches()), steps=args.steps)
     first = trainer.metrics_log[0]["loss"]

@@ -192,10 +192,7 @@ def shard(x, *logical: LogicalAxis, rules: Optional[ShardingRules] = None):
     rules = rules or active_rules()
     dim_sizes = list(x.shape) if hasattr(x, "shape") else None
     spec = rules.spec(*logical, dim_sizes=dim_sizes)
-    try:
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
 # Weight-gather FSDP (§Perf iteration 2): storage shards weights over the
@@ -214,7 +211,4 @@ def use_weight(w, *logical: LogicalAxis):
         fsdp=False, layout="dp" if layout == "dp" else "baseline")
     dim_sizes = list(w.shape) if hasattr(w, "shape") else None
     spec = use_rules.spec(*logical, dim_sizes=dim_sizes)
-    try:
-        return jax.lax.with_sharding_constraint(w, spec)
-    except Exception:
-        return w
+    return jax.lax.with_sharding_constraint(w, spec)

@@ -17,21 +17,37 @@ shows up as slices carrying ``args.step_num``.
 This reader maps those captures onto the lane model the rest of
 :mod:`repro.traceio` uses (one non-overlapping event sequence per thread):
 
+* **workers** — one per device process (``/device:TPU:<n>``, in device
+  order).  A device process has three lines: ``XLA Ops`` (one slice per
+  executed HLO op, loop bodies nested in their ``while`` slice), ``XLA
+  Modules`` and ``Steps``; the last two summarize the same time, so only
+  ``XLA Ops`` becomes the worker's ``device`` lane.  The ``/host:CPU``
+  threads join the first device worker as ``host``, ``host:2``, ... lanes
+  — the host drives the devices and is not a worker of its own.  Their
+  idle time is recorded as zero gap, not inferred: beside a device it is
+  waiting on the device, which the lane model cannot express.  A
+  capture without device processes (the CPU backend) is one worker whose
+  XLA runtime threads (``tf_XLAPjRtCpuClient`` / ``tf_XLAEigen``, or any
+  thread holding ``args.hlo_op`` slices) are ``device`` lanes and whose
+  python thread is the ``host`` lane;
 * **step slicing** — with step annotations present, only events inside the
   selected step's window are kept (``step="last"`` by default: the last —
   warmed-up — step; an int selects a specific ``step_num``; ``None``
-  keeps the whole capture);
+  keeps the whole capture).  Device ops run on the device's clock, which
+  is offset from the host's by up to a millisecond, so a device process
+  is sliced by its own ``Steps`` line: the k-th step from the end of that
+  line is the k-th annotated step from the end;
 * **leaf extraction** — profiler flames nest (a python frame contains its
-  callees; an HLO module slice contains its ops), which violates the lane
-  model, so each ``(pid, tid)`` keeps only its *leaf* slices — the frames
-  where time is actually spent — and residual overlaps are clipped;
-* **lane naming** — threads holding HLO-op slices (or XLA-runtime thread
-  names) become ``device`` lanes, python/host threads become ``host``
-  lanes, anything else keeps a sanitized thread name;
-* **kinds** — from the lane plus the usual name classification
+  callees; a ``while`` slice contains its body's ops), which violates the
+  lane model, so each ``(pid, tid)`` keeps only its *leaf* slices — the
+  frames where time is actually spent — and residual overlaps are clipped;
+* **kinds and costs** — from the lane plus the usual name classification
   (:func:`repro.traceio.events.classify`), so HLO collectives
-  (``all-reduce.N`` ...) land as :data:`TaskKind.COLLECTIVE` with their
-  lane order preserved.
+  (``all-reduce.N``, ``all-gather-start.N`` ...) land as
+  :data:`TaskKind.COLLECTIVE` with their lane order preserved.  TPU op
+  slices also carry ``tf_op`` (the op's jax name stack, split into layer
+  and phase like compiled HLO metadata), ``model_flops`` and
+  ``bytes_accessed``.
 
 XLA's Chrome export carries no flow events on these captures, so
 cross-thread dependencies are not recoverable: the imported graph has
@@ -39,9 +55,9 @@ per-lane program order only, which preserves every duration (what
 calibration fits against) but lets a simulation compact inter-lane idle
 time.
 
-One *worker* per device process — or per host file when the capture is
-CPU-backed (single ``/host:CPU`` process).  Multi-host captures are
-clock-aligned through matched collectives like any other trace set.
+Workers from one host file share that host's clock (identity alignment).
+Multi-host captures are clock-aligned through matched collectives like
+any other trace set.
 """
 
 from __future__ import annotations
@@ -53,18 +69,24 @@ import os
 import re
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
+from repro.core.hlo import split_op_name
+from repro.core.task import DEVICE_STREAM, HOST_THREAD
+
 from .align import ClockAlignment, align_traces, apply_alignment
 from .events import TraceEvent, TraceImportError, WorkerTrace
 from .importer import ImportedCluster, graph_from_events
 
 _US = 1e6     # Chrome microseconds -> seconds
 
-# XLA runtime execution threads (device streams on CPU-backed captures).
-_DEVICE_THREAD = re.compile(
-    r"XLATfrtCpuClient|XlaLauncher|StreamExecutor|TpuDriver|/device:", re.I)
+# XLA runtime threads that execute HLO on CPU-backed captures.
+_DEVICE_THREAD = re.compile(r"XLAPjRtCpuClient|XLAEigen", re.I)
 _HOST_THREAD = re.compile(r"^python$|main_thread|^host", re.I)
 # Background service threads that are not part of the training step.
 _NOISE_THREAD = re.compile(r"llvm-codegen|compile|Profiler|pthread", re.I)
+# The op-level line and the step line of a device process.
+_OPS_LINE = "XLA Ops"
+_STEPS_LINE = "Steps"
+_EPS_US = 1e-3    # one nanosecond of float slack on window edges
 
 
 def find_xla_trace_files(path: str) -> List[str]:
@@ -154,20 +176,14 @@ def _clip_overlaps(evs: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
     return out
 
 
-def _step_window(events: List[Dict[str, Any]],
-                 step: Union[str, int, None]
-                 ) -> Optional[Tuple[float, float]]:
-    """Resolve one annotated step's [start, end] window over a whole file.
+def _step_markers(events: List[Dict[str, Any]]
+                  ) -> Dict[int, Tuple[float, float]]:
+    """``step_num`` -> [start, end] of each annotated step, file-wide.
 
     ``jax.profiler.StepTraceAnnotation`` slices carry ``args.step_num`` —
     but only on the annotating (host) thread, so the window must be
-    computed file-wide and then applied to *every* thread, device lanes
-    included.  ``step="last"`` picks the highest step number (steady
-    state), an int picks that step, ``None`` keeps everything.  Returns
-    ``None`` (keep everything) for unannotated captures.
+    computed file-wide and then applied to other threads too.
     """
-    if step is None:
-        return None
     markers: Dict[int, Tuple[float, float]] = {}
     for e in events:
         num = (e.get("args") or {}).get("step_num")
@@ -176,54 +192,107 @@ def _step_window(events: List[Dict[str, Any]],
         lo, hi = markers.get(int(num), (float("inf"), float("-inf")))
         markers[int(num)] = (min(lo, e["ts"]),
                              max(hi, e["ts"] + e["dur"]))
-    if not markers:
+    return markers
+
+
+def _chosen_step(markers: Dict[int, Tuple[float, float]],
+                 step: Union[str, int, None]) -> Optional[int]:
+    """The annotated step to keep: ``"last"`` picks the highest step number
+    (steady state), an int picks that step, ``None`` (or a capture without
+    annotations) keeps everything."""
+    if step is None or not markers:
         return None
     if step == "last":
-        chosen = max(markers)
-    else:
-        chosen = int(step)
-        if chosen not in markers:
-            raise TraceImportError(
-                f"step {chosen} not in capture (annotated steps: "
-                f"{sorted(markers)})")
-    return markers[chosen]
+        return max(markers)
+    if int(step) not in markers:
+        raise TraceImportError(
+            f"step {int(step)} not in capture (annotated steps: "
+            f"{sorted(markers)})")
+    return int(step)
+
+
+def _device_window(steps_line: List[Dict[str, Any]],
+                   markers: Dict[int, Tuple[float, float]],
+                   chosen: int) -> Tuple[float, float]:
+    """The chosen step's window on a device's own clock: the device's
+    ``Steps`` line and the host annotations both end at the end of the
+    capture, so they are matched from the end."""
+    back = len(markers) - sorted(markers).index(chosen)
+    steps = sorted(steps_line, key=lambda e: e["ts"])
+    if back > len(steps):
+        raise TraceImportError(
+            f"step {chosen} is not on the device's Steps line "
+            f"({len(steps)} device step(s) for {len(markers)} annotated)")
+    e = steps[-back]
+    return e["ts"], e["ts"] + e["dur"]
 
 
 def _select_step(events: List[Dict[str, Any]],
                  window: Optional[Tuple[float, float]]
                  ) -> List[Dict[str, Any]]:
-    """Restrict one thread's X events to a :func:`_step_window` (marker
-    slices themselves are dropped — they are annotations, not work)."""
+    """Restrict one thread's X events to a step window (marker slices
+    themselves are dropped — they are annotations, not work)."""
     if window is None:
         return events
     lo, hi = window
     return [e for e in events
-            if e["ts"] >= lo and e["ts"] + e["dur"] <= hi
+            if e["ts"] >= lo - _EPS_US and e["ts"] + e["dur"] <= hi + _EPS_US
             and (e.get("args") or {}).get("step_num") is None]
 
 
-def _lane_name(thread_name: str, has_hlo: bool, used: Dict[str, int]) -> str:
-    """Map one profiler thread onto a lane name (``device`` / ``host`` /
-    sanitized), deduplicated with ``:<k>`` suffixes.  Host-name patterns
-    win over HLO presence: CPU-backed captures can run small HLO programs
-    inline on the python thread, which is still host time."""
-    if _HOST_THREAD.search(thread_name):
-        base = "host"
-    elif has_hlo or _DEVICE_THREAD.search(thread_name):
-        base = "device"
-    else:
-        base = re.sub(r"[^\w.-]+", "_", thread_name).strip("_") or "aux"
+def _unique(base: str, used: Dict[str, int]) -> str:
+    """``base``, then ``base:2``, ``base:3`` ... for repeated lane names."""
     used[base] = used.get(base, 0) + 1
     return base if used[base] == 1 else f"{base}:{used[base]}"
+
+
+def _lane_name(thread_name: str, has_hlo: bool, used: Dict[str, int]) -> str:
+    """Map one thread of a CPU-backed capture onto a lane name (``device``
+    / ``host`` / sanitized), deduplicated.  Host-name patterns win over HLO
+    presence: CPU-backed captures can run small HLO programs inline on the
+    python thread, which is still host time."""
+    if _HOST_THREAD.search(thread_name):
+        base = HOST_THREAD
+    elif has_hlo or _DEVICE_THREAD.search(thread_name):
+        base = DEVICE_STREAM
+    else:
+        base = re.sub(r"[^\w.-]+", "_", thread_name).strip("_") or "aux"
+    return _unique(base, used)
+
+
+def _append_lane(out: List[TraceEvent], slices: List[Dict[str, Any]],
+                 lane: str, tname: str, gap: Optional[float] = None) -> None:
+    """Append one profiler thread's step-sliced leaf slices to ``out`` as
+    events on ``lane``; ``gap`` is recorded on each (``None`` lets the
+    importer infer it)."""
+    for e in slices:
+        args = e["args"]
+        attrs = {k: v for k, v in args.items()
+                 if isinstance(v, (str, int, float, bool))}
+        attrs["xla_thread"] = tname
+        layer, phase = split_op_name(str(args.get("tf_op", "")))
+        out.append(TraceEvent(
+            name=str(args.get("hlo_op") or e["name"]),
+            thread=lane, ts=e["ts"] / _US, dur=e["dur"] / _US,
+            eid=len(out), gap=gap, layer=layer, phase=phase,
+            flops=float(args.get("model_flops", 0.0)),
+            bytes_accessed=float(args.get("bytes_accessed", 0.0)),
+            attrs=attrs))
+
+
+def _device_ordinal(proc_name: str) -> Tuple[int, str]:
+    m = re.search(r"(\d+)$", proc_name)
+    return (int(m.group(1)) if m else -1, proc_name)
 
 
 def read_xla_trace(path: str, *, step: Union[str, int, None] = "last"
                    ) -> List[WorkerTrace]:
     """Read one per-host ``.trace.json(.gz)`` file into worker traces.
 
-    One worker per device process; CPU-backed captures (a single
-    ``/host:CPU`` process) yield one worker.  Worker numbering here is
-    file-local — :func:`load_xla_profile` renumbers across hosts.
+    One worker per device process, host threads joining the first; a
+    capture without device processes (CPU backend) yields one worker per
+    process.  Worker numbering here is file-local — :func:`load_xla_profile`
+    renumbers across hosts.
     """
     doc = _read_trace_json(path)
     proc_names: Dict[Any, str] = {}
@@ -251,35 +320,64 @@ def read_xla_trace(path: str, *, step: Union[str, int, None] = "last"
         raise TraceImportError(f"{path}: capture has no complete (ph=X) "
                                f"events")
 
-    window = _step_window(
-        [e for evs in by_thread.values() for e in evs], step)
-    traces: List[WorkerTrace] = []
-    for pid in sorted({k[0] for k in by_thread}, key=str):
-        threads = sorted((k for k in by_thread if k[0] == pid),
-                         key=lambda k: str(k[1]))
-        proc_is_device = "/device:" in proc_names.get(pid, "")
+    markers = _step_markers([e for evs in by_thread.values() for e in evs])
+    chosen = _chosen_step(markers, step)
+    host_window = markers[chosen] if chosen is not None else None
+
+    def threads_of(pid: Any) -> List[Tuple[Tuple[Any, Any], str]]:
+        keys = sorted((k for k in by_thread if k[0] == pid),
+                      key=lambda k: str(k[1]))
+        return [(k, thread_names.get(k, f"tid{k[1]}")) for k in keys]
+
+    def leaves(key: Tuple[Any, Any],
+               window: Optional[Tuple[float, float]]) -> List[Dict[str, Any]]:
+        return _clip_overlaps(_leaf_slices(_select_step(by_thread[key],
+                                                        window)))
+
+    pids = sorted({k[0] for k in by_thread}, key=str)
+    device_pids = sorted((p for p in pids
+                          if "/device:" in proc_names.get(p, "")),
+                         key=lambda p: _device_ordinal(proc_names[p]))
+    workers: List[Tuple[Any, List[TraceEvent]]] = []
+    if device_pids:
+        for pid in device_pids:
+            lines = {tname: k for k, tname in threads_of(pid)}
+            window = host_window
+            if chosen is not None and _STEPS_LINE in lines:
+                window = _device_window(by_thread[lines[_STEPS_LINE]],
+                                        markers, chosen)
+            events: List[TraceEvent] = []
+            if _OPS_LINE in lines:
+                _append_lane(events, leaves(lines[_OPS_LINE], window),
+                             DEVICE_STREAM, _OPS_LINE)
+            workers.append((pid, events))
         used: Dict[str, int] = {}
-        events: List[TraceEvent] = []
-        for key in threads:
-            tname = thread_names.get(key, f"tid{key[1]}")
-            if _NOISE_THREAD.search(tname):
+        for pid in pids:
+            if pid in device_pids:
                 continue
-            evs = _select_step(by_thread[key], window)
-            evs = _clip_overlaps(_leaf_slices(evs))
-            if not evs:
-                continue
-            has_hlo = any("hlo_op" in e["args"] for e in evs)
-            lane = _lane_name(tname, has_hlo or (
-                proc_is_device and not _HOST_THREAD.search(tname)), used)
-            for e in evs:
-                args = e["args"]
-                attrs = {k: v for k, v in args.items()
-                         if isinstance(v, (str, int, float, bool))}
-                attrs["xla_thread"] = tname
-                events.append(TraceEvent(
-                    name=str(args.get("hlo_op") or e["name"]),
-                    thread=lane, ts=e["ts"] / _US, dur=e["dur"] / _US,
-                    eid=len(events), attrs=attrs))
+            for key, tname in threads_of(pid):
+                slices = [] if _NOISE_THREAD.search(tname) \
+                    else leaves(key, host_window)
+                if slices:
+                    # host idle beside a device is mostly waiting on it; no
+                    # edge in the lane model says so, and as inferred host
+                    # work it would pin every what-if to the captured step
+                    _append_lane(workers[0][1], slices,
+                                 _unique(HOST_THREAD, used), tname, gap=0.0)
+    else:
+        for pid in pids:
+            used = {}
+            events = []
+            for key, tname in threads_of(pid):
+                slices = [] if _NOISE_THREAD.search(tname) \
+                    else leaves(key, host_window)
+                if slices:
+                    has_hlo = any("hlo_op" in e["args"] for e in slices)
+                    _append_lane(events, slices,
+                                 _lane_name(tname, has_hlo, used), tname)
+            workers.append((pid, events))
+    traces: List[WorkerTrace] = []
+    for pid, events in workers:
         if events:
             traces.append(WorkerTrace(worker=len(traces), events=events,
                                       source=f"{path}#pid={pid}"))

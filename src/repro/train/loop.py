@@ -56,9 +56,13 @@ class Trainer:
 
     # ------------------------------------------------------------- state
     def init_state(self) -> Dict[str, Any]:
-        params = init_params(self.cfg, jax.random.PRNGKey(self.tc.seed))
-        return {"params": params, "opt": self.opt.init(params),
-                "step": jnp.zeros((), jnp.int32)}
+        def init():
+            params = init_params(self.cfg, jax.random.PRNGKey(self.tc.seed))
+            return {"params": params, "opt": self.opt.init(params),
+                    "step": jnp.zeros((), jnp.int32)}
+        sh = self.state_shardings()
+        # under a mesh, each device materializes only its shard of the state
+        return init() if sh is None else jax.jit(init, out_shardings=sh)()
 
     def state_shardings(self):
         if self.mesh is None:
@@ -99,10 +103,14 @@ class Trainer:
         it = iter(batches)
         for i in range(start, steps):
             batch = {k: jnp.asarray(v) for k, v in next(it).items()}
-            t0 = time.time()
-            state, metrics = step_fn(state, batch)
-            metrics = {k: float(jax.device_get(v)) for k, v in metrics.items()}
-            dt = time.time() - t0
+            # a no-op unless a profiler trace is active; marks the step
+            # window the capture importer slices on
+            with jax.profiler.StepTraceAnnotation("train", step_num=i):
+                t0 = time.time()
+                state, metrics = step_fn(state, batch)
+                metrics = {k: float(jax.device_get(v))
+                           for k, v in metrics.items()}
+                dt = time.time() - t0
             self.straggler.record(i, dt)
             metrics.update(step=i, step_time_s=dt)
             self.metrics_log.append(metrics)

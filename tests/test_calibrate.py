@@ -39,6 +39,17 @@ def perturbed_cost() -> CostModel:
     return CostModel(kind_scales={"compute": 1.3}, ici_factor=0.5)
 
 
+# ============================================================ hardware peaks
+@pytest.mark.parametrize("kind", ["TPU v5 lite", "cpu", "TPU v4"])
+def test_peaks_by_device_kind(kind):
+    from repro.core import PEAKS, TPU_V5E, hardware_for
+    if kind == "TPU v5 lite":
+        assert hardware_for(kind) is PEAKS[kind] is TPU_V5E
+    else:
+        with pytest.raises(KeyError, match="no published peaks"):
+            hardware_for(kind)
+
+
 # ====================================================== parameter introspection
 class TestFittableConstants:
     def test_typed_list_with_bounds(self):

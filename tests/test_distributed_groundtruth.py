@@ -68,13 +68,13 @@ _DDP_SNIPPET = textwrap.dedent("""
     pred_slowdown = pred / base
 
     # --- ground truth: real 8-way DP on host devices
-    from repro.compat import make_mesh, set_mesh
-    mesh = make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     xg = jnp.concatenate([x1] * 8, axis=0)
     xg = jax.device_put(xg, NamedSharding(mesh, P("data", None, None)))
     Wr = jax.device_put(W, NamedSharding(mesh, P()))
     t1 = measure_wallclock(step, W, x1, iters=20)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         t8 = measure_wallclock(step, Wr, xg, iters=20)
     true_slowdown = t8 / t1
 
@@ -113,12 +113,12 @@ _ELASTIC_SNIPPET = textwrap.dedent("""
     tree = {{"w": jnp.arange(64.0).reshape(8, 8),
              "b": jnp.ones((16,), jnp.bfloat16)}}
 
-    from repro.compat import make_mesh
-    mesh4 = make_mesh((4,), ("data",), devices=jax.devices()[:4])
+    auto = (jax.sharding.AxisType.Auto,)
+    mesh4 = jax.make_mesh((4,), ("data",), auto, devices=jax.devices()[:4])
     sharded = jax.device_put(tree, NamedSharding(mesh4, P("data")))
     save_checkpoint(tmp, 11, sharded)
 
-    mesh2 = make_mesh((2,), ("data",), devices=jax.devices()[:2])
+    mesh2 = jax.make_mesh((2,), ("data",), auto, devices=jax.devices()[:2])
     sh2 = {{"w": NamedSharding(mesh2, P("data", None)),
             "b": NamedSharding(mesh2, P("data"))}}
     out, step = restore_checkpoint(tmp, tree, shardings=sh2)

@@ -379,7 +379,7 @@ class TestCounterRoundTrip:
                      "tid": 0, "args": {"name": pname}},
                     {"ph": "M", "name": "thread_name", "pid": pid,
                      "tid": tid, "args": {"name": tname}}]
-        evs = meta(7, 1, "/host:CPU", "tf_XLATfrtCpuClient/1")
+        evs = meta(7, 1, "/host:CPU", "tf_XLAPjRtCpuClient/1")
         evs.append({"ph": "X", "name": "dot.1", "pid": 7, "tid": 1,
                     "ts": 100.0, "dur": 200.0,
                     "args": {"hlo_op": "dot.1", "hlo_module": "jit_f"}})

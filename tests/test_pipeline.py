@@ -48,11 +48,11 @@ _SPMD_SNIPPET = textwrap.dedent("""
     import numpy as np
     from functools import partial
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map, make_mesh
     from repro.parallel import gpipe_spmd
 
     S, M, mb, d = 4, 6, 2, 8
-    mesh = make_mesh((S,), ("stage",))
+    mesh = jax.make_mesh((S,), ("stage",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     key = jax.random.PRNGKey(0)
     Ws = jax.random.normal(key, (S, d, d)) * 0.3          # one weight per stage
     x = jax.random.normal(jax.random.fold_in(key, 1), (M, mb, d))
@@ -63,9 +63,9 @@ _SPMD_SNIPPET = textwrap.dedent("""
     def spmd(W, xmb):
         return gpipe_spmd(partial(stage_body, W), xmb, n_microbatches=M)
 
-    f = shard_map(spmd, mesh=mesh,
-                  in_specs=(P("stage", None, None), P(None, None, None)),
-                  out_specs=P(None, None, None))
+    f = jax.shard_map(spmd, mesh=mesh,
+                      in_specs=(P("stage", None, None), P(None, None, None)),
+                      out_specs=P(None, None, None))
     got = jax.jit(f)(Ws, x)
 
     want = x

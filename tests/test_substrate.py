@@ -127,8 +127,8 @@ class TestCheckpoint:
         n = len(jax.devices())
         tree = {"w": jnp.arange(16.0).reshape(4, 4)}
         save_checkpoint(str(tmp_path), 0, tree)
-        from repro.compat import make_mesh
-        mesh = make_mesh((1,), ("data",))
+        mesh = jax.make_mesh((1,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         sh = {"w": NamedSharding(mesh, P("data", None))}
         out, _ = restore_checkpoint(str(tmp_path), tree, shardings=sh)
         np.testing.assert_array_equal(np.asarray(out["w"]),
@@ -185,6 +185,26 @@ class TestRuntime:
         assert mon.record(8, 5.0) is True
         assert mon.flagged == [8]
 
+    @pytest.mark.parametrize("env", ["/elsewhere/cache", None])
+    def test_compile_cache_dir(self, monkeypatch, env):
+        from repro.runtime.compile_cache import CHECKOUT_CACHE, \
+            use_compile_cache
+        if env is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        else:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+        before = jax.config.jax_compilation_cache_dir
+        try:
+            got = use_compile_cache()
+            after = jax.config.jax_compilation_cache_dir
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
+        if env is None:
+            assert got == after == str(CHECKOUT_CACHE)
+            assert CHECKOUT_CACHE.parent.joinpath("pyproject.toml").exists()
+        else:
+            assert got == env and after == before   # left to jax
+
 
 # --------------------------------------------------------------- sharding
 class TestSharding:
@@ -193,9 +213,9 @@ class TestSharding:
         assert all(s is None for s in spec)
 
     def test_rules_under_mesh(self):
-        from repro.compat import make_mesh, set_mesh
-        mesh = make_mesh((1,), ("model",))
-        with set_mesh(mesh):
+        mesh = jax.make_mesh((1,), ("model",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
+        with jax.set_mesh(mesh):
             rules = ShardingRules()
             spec = rules.spec("batch", "heads", dim_sizes=[4, 4])
             # model axis size 1 -> nothing shardable but no error

@@ -79,8 +79,7 @@ _DRYRUN_SNIPPET = textwrap.dedent("""
             cfg = get_smoke_config(arch)
             kind = registry.SHAPES[shape_name].kind
             spec = registry.ShapeSpec(shape_name, seq, gb, kind)
-            from repro import compat
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 cell = build_cell(cfg, spec, mesh)
                 compiled = cell.lower().compile()
                 agg = aggregate_costs(parse_hlo_module(compiled.as_text()))

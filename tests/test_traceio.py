@@ -614,7 +614,7 @@ class TestXlaImport:
                  "args": {"name": tname}}]
 
     def _step_capture(self):
-        evs = self._meta(7, 1, "/host:CPU", "tf_XLATfrtCpuClient/1")
+        evs = self._meta(7, 1, "/host:CPU", "tf_XLAPjRtCpuClient/1")
         evs += self._meta(7, 2, "/host:CPU", "python")[1:]
         for step, base in ((0, 1000.0), (1, 2000.0)):
             evs.append({"ph": "X", "name": "train", "pid": 7, "tid": 2,
@@ -705,7 +705,7 @@ class TestXlaImport:
         assert imp.num_workers == 2
 
     def test_capture_without_steps_keeps_everything(self, tmp_path):
-        evs = self._meta(7, 1, "/host:CPU", "tf_XLATfrtCpuClient/1")
+        evs = self._meta(7, 1, "/host:CPU", "tf_XLAPjRtCpuClient/1")
         evs.append({"ph": "X", "name": "dot.9", "pid": 7, "tid": 1,
                     "ts": 100.0, "dur": 10.0, "args": {"hlo_op": "dot.9"}})
         d = self._profile_dir(tmp_path, evs)
@@ -719,3 +719,79 @@ class TestXlaImport:
             traceio.load_xla_profile(d)
         with pytest.raises(TraceImportError, match="no XLA profile"):
             traceio.load_xla_profile(str(tmp_path / "nope"))
+
+
+class TestRecordedTpuCapture:
+    """An excerpt of a real one-chip capture (TPU v5e, ``tinyllama-1.1b``
+    training steps 3-4 of ``chip_smoke.py``): the first few milliseconds of
+    each step's ``XLA Ops`` line, the full ``Steps`` and ``XLA Modules``
+    lines, and the host threads around the step markers."""
+
+    PATH = os.path.join(os.path.dirname(__file__), "golden",
+                        "tpu_v5e_train_step.trace.json.gz")
+
+    @staticmethod
+    def _raw_lines(path):
+        import gzip
+        with gzip.open(path, "rt") as f:
+            doc = json.load(f)
+        names = {(e["pid"], e["tid"]): e["args"]["name"]
+                 for e in doc["traceEvents"]
+                 if e.get("ph") == "M" and e["name"] == "thread_name"}
+        lines = {}
+        for e in doc["traceEvents"]:
+            if e.get("ph") == "X":
+                lines.setdefault(names.get((e["pid"], e["tid"])),
+                                 []).append(e)
+        return lines
+
+    def test_one_worker_one_device_lane(self):
+        imp = traceio.load_xla_profile(self.PATH)
+        assert imp.num_workers == 1               # /host:CPU is no worker
+        lanes = {e.thread for e in imp.traces[0].events}
+        assert "device" in lanes
+        assert all(lane == "device" or lane.startswith("host")
+                   for lane in lanes)
+        dev = [e for e in imp.traces[0].events if e.thread == "device"]
+        assert all(e.attrs["xla_thread"] == "XLA Ops" for e in dev)
+        kinds = {t.kind for t in imp.graphs[0].tasks()
+                 if t.thread == "device"}
+        assert kinds == {TaskKind.COMPUTE}
+        # host idle beside the device is waiting, not inferred host work
+        assert all(t.gap == 0.0 for t in imp.graphs[0].tasks()
+                   if t.thread.startswith("host"))
+
+    def test_device_time_is_counted_once(self):
+        imp = traceio.load_xla_profile(self.PATH)
+        dev = sorted((e for e in imp.traces[0].events
+                      if e.thread == "device"), key=lambda e: e.ts)
+        for a, b in zip(dev, dev[1:]):
+            assert b.ts >= a.end - 1e-12          # no overlapping slices
+        busy = sum(e.dur for e in dev)
+        assert busy <= dev[-1].end - dev[0].ts
+        # the module slice spans the whole step; only its ops are counted
+        module = max(e["dur"] for e in self._raw_lines(self.PATH)
+                     ["XLA Modules"]) / 1e6
+        assert busy < 0.1 * module
+
+    def test_steps_sliced_on_the_device_clock(self):
+        """The device starts a step before the host's step marker opens on
+        the host clock; slicing by the device's own Steps line keeps the
+        step's first op."""
+        lines = self._raw_lines(self.PATH)
+        for step, nth in ((3, 0), (4, 1)):
+            imp = traceio.load_xla_profile(self.PATH, step=step)
+            dev = [e for e in imp.traces[0].events if e.thread == "device"]
+            marker = next(e for e in lines["python"]
+                          if e["args"].get("step_num") == str(step))
+            module = sorted(lines["XLA Modules"], key=lambda e: e["ts"])[nth]
+            assert min(e.ts for e in dev) * 1e6 < marker["ts"]
+            assert min(e.ts for e in dev) * 1e6 == pytest.approx(
+                module["ts"], abs=1.0)
+
+    def test_ops_carry_layer_phase_and_cost(self):
+        imp = traceio.load_xla_profile(self.PATH)
+        tasks = [t for t in imp.graphs[0].tasks() if t.thread == "device"]
+        assert any(t.phase == "fwd" and t.layer for t in tasks)
+        assert any(t.flops > 0 for t in tasks)
+        assert all(t.bytes_accessed >= 0 for t in tasks)
