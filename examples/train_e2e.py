@@ -46,8 +46,7 @@ def main() -> None:
     trainer.fit(Prefetcher(batches()))
     losses = [m["loss"] for m in trainer.metrics_log]
     print(f"loss: {losses[0]:.4f} -> {losses[-1]:.4f}; "
-          f"median step {sorted(m['step_time_s'] for m in trainer.metrics_log)[len(losses)//2]*1e3:.0f} ms; "
-          f"straggler flags: {trainer.straggler.flagged}")
+          f"median step {sorted(m['step_time_s'] for m in trainer.metrics_log)[len(losses)//2]*1e3:.0f} ms")
 
 
 if __name__ == "__main__":

@@ -16,7 +16,8 @@ Neither submodule imports ``repro.*`` at module scope, so ``repro.obs``
 is importable from anywhere in the package without cycles.
 """
 
-from repro.obs.spans import configure, enabled, span, telemetry_path
+from repro.obs.spans import (configure, enabled, flush, span,
+                              telemetry_path)
 from repro.obs.timeline import (Timeline, TimelineSet, check_result_fresh,
                                 compute_timelines, format_timeline_report,
                                 interval_overlap, interval_union,
@@ -26,5 +27,5 @@ __all__ = [
     "Timeline", "TimelineSet", "check_result_fresh", "compute_timelines",
     "format_timeline_report", "interval_overlap", "interval_union",
     "lane_utilization",
-    "span", "configure", "enabled", "telemetry_path",
+    "span", "configure", "enabled", "flush", "telemetry_path",
 ]

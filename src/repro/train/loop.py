@@ -1,10 +1,18 @@
-"""Training loop: jit-compiled step, sharded state, checkpoints, fault hooks.
+"""Training loop: jit-compiled step, sharded state, checkpoints.
 
 Composition of the substrate layers:
   models.make_train_step  (loss + AdamW update, grad-accum aware)
   data.SyntheticLM        (per-host batch slices, prefetch)
   ckpt.CheckpointManager  (atomic, async, elastic re-shard)
-  runtime.*               (heartbeat, straggler monitor, retry driver)
+  obs.span                (the loop's phases, when telemetry is on)
+
+``fit`` records a ``train.fit`` span around the call, ``train.init_state``
+around restoring or making the state, and per step ``train.next_batch``,
+``train.to_device``, ``train.dispatch`` (the first call's trace, lower and
+compile included), ``train.sync`` (the metrics' ``device_get``),
+``train.hooks`` and, on a save, ``train.checkpoint``, each with ``step``,
+and writes the records out as it returns.  Disabled, each span costs one
+``None`` check.
 
 Works on a laptop (no mesh), the single-pod mesh, and the multi-pod mesh —
 the sharding rules resolve against whatever mesh is active.
@@ -24,7 +32,7 @@ from repro.models.model import (ModelConfig, init_params, make_train_step)
 from repro.models.paramdecl import SpecLeaf, specs_of
 from repro.optim import AdamW
 from repro.ckpt import CheckpointManager
-from repro.runtime import Heartbeat, StragglerMonitor
+from repro.obs import flush, span
 from repro.sharding import ShardingRules, DEFAULT_RULES
 
 
@@ -36,7 +44,6 @@ class TrainerConfig:
     ckpt_dir: Optional[str] = None
     ckpt_async: bool = True
     seed: int = 0
-    straggler_threshold: float = 2.5
 
 
 class Trainer:
@@ -50,7 +57,6 @@ class Trainer:
         self.rules = rules
         self.step_fn = make_train_step(cfg, self.opt)
         self.ckpt = (CheckpointManager(tc.ckpt_dir) if tc.ckpt_dir else None)
-        self.straggler = StragglerMonitor(threshold=tc.straggler_threshold)
         self.metrics_log: list = []
         self._jitted = None
 
@@ -97,35 +103,44 @@ class Trainer:
             hooks: Optional[Callable[[int, Dict], None]] = None
             ) -> Dict[str, Any]:
         steps = steps or self.tc.steps
-        state = self.restore_or_init()
-        start = int(jax.device_get(state["step"]))
-        step_fn = self.jitted_step()
-        it = iter(batches)
-        for i in range(start, steps):
-            batch = {k: jnp.asarray(v) for k, v in next(it).items()}
-            # a no-op unless a profiler trace is active; marks the step
-            # window the capture importer slices on
-            with jax.profiler.StepTraceAnnotation("train", step_num=i):
-                t0 = time.time()
-                state, metrics = step_fn(state, batch)
-                metrics = {k: float(jax.device_get(v))
-                           for k, v in metrics.items()}
-                dt = time.time() - t0
-            self.straggler.record(i, dt)
-            metrics.update(step=i, step_time_s=dt)
-            self.metrics_log.append(metrics)
-            if hooks:
-                hooks(i, metrics)
-            if self.tc.log_every and (i % self.tc.log_every == 0):
-                print(f"step {i:6d} loss={metrics['loss']:.4f} "
-                      f"gnorm={metrics.get('grad_norm', 0):.3f} "
-                      f"dt={dt*1e3:.1f}ms", flush=True)
-            if self.ckpt and ((i + 1) % self.tc.ckpt_every == 0
-                              or i + 1 == steps):
-                if self.tc.ckpt_async:
-                    self.ckpt.save_async(i, state)
-                else:
-                    self.ckpt.save(i, state)
-        if self.ckpt:
-            self.ckpt.wait()
+        with span("train.fit", steps=steps):
+            with span("train.init_state"):
+                state = self.restore_or_init()
+            start = int(jax.device_get(state["step"]))
+            step_fn = self.jitted_step()
+            it = iter(batches)
+            for i in range(start, steps):
+                with span("train.next_batch", step=i):
+                    rows = next(it)
+                with span("train.to_device", step=i):
+                    batch = {k: jnp.asarray(v) for k, v in rows.items()}
+                # a no-op unless a profiler trace is active; marks the step
+                # window the capture importer slices on
+                with jax.profiler.StepTraceAnnotation("train", step_num=i):
+                    t0 = time.perf_counter()
+                    with span("train.dispatch", step=i):
+                        state, metrics = step_fn(state, batch)
+                    with span("train.sync", step=i):
+                        metrics = {k: float(jax.device_get(v))
+                                   for k, v in metrics.items()}
+                    dt = time.perf_counter() - t0
+                metrics.update(step=i, step_time_s=dt)
+                self.metrics_log.append(metrics)
+                with span("train.hooks", step=i):
+                    if hooks:
+                        hooks(i, metrics)
+                    if self.tc.log_every and (i % self.tc.log_every == 0):
+                        print(f"step {i:6d} loss={metrics['loss']:.4f} "
+                              f"gnorm={metrics.get('grad_norm', 0):.3f} "
+                              f"dt={dt*1e3:.1f}ms", flush=True)
+                if self.ckpt and ((i + 1) % self.tc.ckpt_every == 0
+                                  or i + 1 == steps):
+                    with span("train.checkpoint", step=i):
+                        if self.tc.ckpt_async:
+                            self.ckpt.save_async(i, state)
+                        else:
+                            self.ckpt.save(i, state)
+            if self.ckpt:
+                self.ckpt.wait()
+        flush()             # the loop's records reach the file as fit ends
         return state

@@ -14,9 +14,15 @@ Covers the ISSUE's observability contracts:
 * counter round-trip: counter-carrying Chrome / XProf exports re-import
   byte-identically to counter-free ones;
 * self-instrumentation spans: nested JSONL emission, error tagging,
-  free disabled path, and the hot-path wiring (build/retune/sweep/import).
+  free disabled path, and the hot-path wiring (build/retune/sweep/import);
+  records buffered and written in bulk (at a fixed count, at ``configure``,
+  as ``Trainer.fit`` returns), JAX's compile listeners registered and
+  removed with it;
+* ``Trainer.fit``'s phase spans in a CPU ``jax.profiler`` capture, and the
+  compile records that name the step that compiled.
 """
 
+import glob
 import gzip
 import json
 import os
@@ -463,3 +469,188 @@ class TestSpans:
         assert {"traceio.load_trace_dir", "cluster.from_worker_graphs",
                 "cluster.build", "cluster.retune",
                 "scenario.sweep_point"} <= names
+
+    def test_records_buffered_until_configure(self, tmp_path):
+        first, second = str(tmp_path / "a.jsonl"), str(tmp_path / "b.jsonl")
+        spans_mod.configure(first)
+        with span("a"):
+            pass
+        assert not os.path.exists(first)        # nothing written yet
+        spans_mod.configure(second)             # flushes to the old path
+        with span("b"):
+            pass
+        (rec,) = self._read(first)
+        assert rec["name"] == "a" and rec["t0"] > 0 and rec["dur_s"] >= 0
+        assert not os.path.exists(second)
+        spans_mod.configure(None)
+        assert [r["name"] for r in self._read(second)] == ["b"]
+        assert [r["name"] for r in self._read(first)] == ["a"]
+
+    def test_written_in_bulk_at_fixed_count(self, tmp_path):
+        """A long run's records reach the file before ``configure(None)``:
+        memory stays bounded and a killed process keeps what was written."""
+        path = str(tmp_path / "spans.jsonl")
+        spans_mod.configure(path)
+        for i in range(spans_mod._FLUSH_AT - 1):
+            with span("s", i=i):
+                pass
+        assert not os.path.exists(path)
+        with span("s", i=spans_mod._FLUSH_AT - 1):
+            pass
+        recs = self._read(path)                 # still enabled
+        assert [r["attrs"]["i"] for r in recs] == \
+            list(range(spans_mod._FLUSH_AT))
+        assert spans_mod._records == []
+        with span("tail"):
+            pass
+        spans_mod.configure(None)
+        assert self._read(path)[-1]["name"] == "tail"
+        assert len(self._read(path)) == spans_mod._FLUSH_AT + 1
+
+    def test_compile_listeners_follow_configure(self, tmp_path):
+        import jax
+        from jax._src import monitoring
+        spans_mod.configure(str(tmp_path / "spans.jsonl"))
+        assert spans_mod._on_time_span in \
+            monitoring.get_event_time_span_listeners()
+        assert spans_mod._on_duration in \
+            monitoring.get_event_duration_listeners()
+        with span("outer", step=7):
+            jax.jit(lambda x: x * 3 + 1)(jax.numpy.ones(5)).block_until_ready()
+        spans_mod.configure(None)
+        assert spans_mod._on_time_span not in \
+            monitoring.get_event_time_span_listeners()
+        assert spans_mod._on_duration not in \
+            monitoring.get_event_duration_listeners()
+        recs = self._read(str(tmp_path / "spans.jsonl"))
+        jaxr = [r for r in recs if r["name"].startswith("jax.")]
+        assert {"jax.trace", "jax.lower", "jax.compile"} <= \
+            {r["name"] for r in jaxr}
+        for r in jaxr:
+            assert r["span"] == "outer." + r["name"]
+            assert r["attrs"]["step"] == 7 and r["attrs"]["fun_name"]
+        (outer,) = [r for r in recs if r["name"] == "outer"]
+        # on one clock with the enclosing span
+        assert all(outer["t0"] <= r["t0"] and r["t0"] + r["dur_s"]
+                   <= outer["t0"] + outer["dur_s"] + 1e-3 for r in jaxr)
+
+
+# ======================================================= Trainer.fit's spans
+PHASES = ("train.next_batch", "train.to_device", "train.dispatch",
+          "train.sync")
+
+
+def _tiny_fit(tmp_path, seqs, capture=None):
+    """A smoke-size ``Trainer.fit`` of ``len(seqs)`` steps, step i fed rows
+    of length ``seqs[i]``, with telemetry on (and a profiler capture into
+    ``capture``); returns the span records as written when ``fit``
+    returned, and as written once telemetry was turned off."""
+    import jax
+    from repro.configs import get_smoke_config
+    from repro.data import make_batch
+    from repro.optim import AdamW
+    from repro.train import Trainer, TrainerConfig
+    cfg = get_smoke_config("tinyllama-1.1b")
+    rows = (make_batch(cfg, seq_len=s, batch=2, step=i)
+            for i, s in enumerate(seqs))
+    path = str(tmp_path / "fit.jsonl")
+    spans_mod.configure(path)
+    try:
+        trainer = Trainer(cfg, TrainerConfig(steps=len(seqs), log_every=0),
+                          optimizer=AdamW(lr=1e-3))
+        if capture:
+            # host TraceMe events only: the Python tracer's frames of the
+            # first step's compile would fill the capture's event limit
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(capture, profiler_options=opts)
+        try:
+            trainer.fit(rows)
+        finally:
+            if capture:
+                jax.profiler.stop_trace()
+        at_exit = _read_jsonl(path)
+    finally:
+        spans_mod.configure(None)
+    return at_exit, _read_jsonl(path)
+
+
+def _read_jsonl(path):
+    if not os.path.exists(path):
+        return []
+    with open(path) as f:
+        return [json.loads(line) for line in f]
+
+
+class TestTrainerSpans:
+    @pytest.fixture(scope="class")
+    def captured(self, tmp_path_factory):
+        tmp = tmp_path_factory.mktemp("fit")
+        at_exit, recs = _tiny_fit(tmp, [16, 16, 16],
+                                  capture=str(tmp / "cap"))
+        (trace,) = glob.glob(str(tmp / "cap" / "plugins" / "profile" / "*"
+                                 / "*.trace.json.gz"))
+        with gzip.open(trace) as f:
+            events = json.load(f)["traceEvents"]
+        pname = {e["pid"]: e["args"]["name"] for e in events
+                 if e.get("ph") == "M" and e["name"] == "process_name"}
+        tname = {(e["pid"], e["tid"]): e["args"]["name"] for e in events
+                 if e.get("ph") == "M" and e["name"] == "thread_name"}
+        host = [e for e in events if e.get("ph") == "X"
+                and pname.get(e["pid"], "").startswith("/host:")
+                and tname.get((e["pid"], e["tid"]), "").startswith("python")]
+        return recs, host, at_exit
+
+    def test_phase_spans_in_the_capture(self, captured):
+        _, host, _ = captured
+        steps = {int(e["args"]["step_num"]): e for e in host
+                 if e["name"] == "train"}
+        assert sorted(steps) == [0, 1, 2]
+        for i, marker in steps.items():
+            m0, m1 = marker["ts"], marker["ts"] + marker["dur"]
+            prev_end = (steps[i - 1]["ts"] + steps[i - 1]["dur"]
+                        if i else float("-inf"))
+            for name in PHASES:
+                (e,) = [e for e in host if e["name"].split("#")[0] == name
+                        and e.get("args", {}).get("step") == str(i)]
+                a, b = e["ts"], e["ts"] + e["dur"]
+                if name in ("train.dispatch", "train.sync"):
+                    # inside the step's StepTraceAnnotation
+                    assert m0 <= a and b <= m1, (name, i)
+                else:
+                    # the step's rows are drawn and moved before it
+                    assert prev_end <= a and b <= m0, (name, i)
+
+    def test_records_per_step(self, captured):
+        recs, _, _ = captured
+        for name in PHASES + ("train.hooks",):
+            got = [r["attrs"]["step"] for r in recs if r["name"] == name]
+            assert got == [0, 1, 2], name
+            assert all(r["span"] == "train.fit." + name
+                       for r in recs if r["name"] == name)
+        assert [r["name"] for r in recs].count("train.fit") == 1
+        assert [r["name"] for r in recs].count("train.init_state") == 1
+
+    def test_written_when_fit_returns(self, captured):
+        recs, _, at_exit = captured
+        assert at_exit == recs                  # nothing left in memory
+        assert at_exit[-1]["name"] == "train.fit"
+
+    def test_compile_records_carry_step_zero(self, captured):
+        recs, _, _ = captured
+        jaxr = [r for r in recs if r["name"].startswith("jax.")]
+        assert jaxr and all(r["span"].startswith("train.fit.")
+                            for r in jaxr)
+        assert {r["attrs"]["step"] for r in jaxr
+                if "step" in r["attrs"]} == {0}
+        assert any(r["name"] == "jax.compile"
+                   and r["attrs"].get("step") == 0
+                   and r["attrs"].get("fun_name") == "train_step"
+                   and r["span"] == "train.fit.train.dispatch.jax.compile"
+                   for r in jaxr)
+
+    def test_recompile_names_its_step(self, tmp_path):
+        _, recs = _tiny_fit(tmp_path, [16, 16, 24])
+        compiles = [r["attrs"] for r in recs if r["name"] == "jax.compile"
+                    and r["attrs"].get("fun_name") == "train_step"]
+        assert [a["step"] for a in compiles] == [0, 2]
