@@ -33,27 +33,17 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> Tuple[jax.Array, int]:
 
 
 # ------------------------------------------------------------------ flash
-@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))
+@functools.partial(jax.jit, static_argnames=("causal",))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool = True, block_q: int = 128,
-                    block_k: int = 128) -> jax.Array:
-    """q: (B, H, S, D); k/v: (B, KH, S, D).  Pads D->128k, S->block mult."""
-    B, H, S, D = q.shape
-    import math
-    qp, _ = _pad_to(q, 3, 128)
-    kp, _ = _pad_to(k, 3, 128)
-    vp, _ = _pad_to(v, 3, 128)
-    bq = min(block_q, max(8, S))
-    bk = min(block_k, max(8, S))
-    sm = max(bq, bk)
-    qp, _ = _pad_to(qp, 2, sm)
-    kp, _ = _pad_to(kp, 2, sm)
-    vp, _ = _pad_to(vp, 2, sm)
-    # padded key positions are masked via kv_len; scale uses the real D
-    out = _fa.flash_attention(qp, kp, vp, causal=causal, block_q=bq,
-                              block_k=bk, sm_scale=1.0 / math.sqrt(D),
-                              kv_len=S, interpret=_interpret())
-    return out[:, :, :S, :D]
+                    causal: bool = True) -> jax.Array:
+    """q: (B, H, S, D); k/v: (B, KH, S, D).  Pads S to the kernel's block
+    multiple (padded keys are masked); differentiable in q, k and v."""
+    S = q.shape[2]
+    Sp = _fa.padded_len(S)
+    qp, kp, vp = (_pad_to(x, 2, Sp)[0] for x in (q, k, v))
+    out = _fa.flash_attention(qp, kp, vp, causal=causal, kv_len=S,
+                              interpret=_interpret())
+    return out[:, :, :S]
 
 
 # ------------------------------------------------------------- fused adam

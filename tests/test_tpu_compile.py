@@ -3,8 +3,9 @@
 The TPU compiler refuses what interpret mode and the CPU backend accept: a
 kernel tile the chip cannot lay out, or a step that does not fit the chip's
 memory.  These tests compile the four Pallas kernels at the widths the main
-path runs them and the full-width ``tinyllama-1.1b`` training step of
-``chip_smoke.py`` for one chip of a ``v5e:2x2`` topology.
+path runs them (the flash kernel's backward too) and the full-width
+``tinyllama-1.1b`` training step of ``chip_smoke.py`` for one chip of a
+``v5e:2x2`` topology, and training attention on all four chips.
 """
 
 import os
@@ -19,6 +20,7 @@ from repro.core import extract_graph, parse_hlo_module, aggregate_costs
 from repro.core.task import DEVICE_STREAM
 from repro.kernels import dgc_topk, flash_attention, fused_adam, rmsnorm
 from repro.models.model import count_params
+from repro.models import attention
 from repro.train import Trainer, TrainerConfig
 
 
@@ -53,8 +55,9 @@ def _sds(shape, dtype, sharding):
 def _kernel_case(name, chip):
     """(kernel call with interpret=False, argument shapes) at the widths the
     main path uses: tinyllama's attention heads over 4096 tokens (head dim
-    64 padded to a 128 lane by ``ops``), rmsnorm over 32768 rows of 2048,
-    fused Adam and DGC over 2^24 parameters."""
+    128), the backward at the ``internvl2-1b.train`` cell's widths (3 x 4096,
+    14 q / 2 kv heads of 64), rmsnorm over 32768 rows of 2048, fused Adam
+    and DGC over 2^24 parameters."""
     f32, bf16 = jnp.float32, jnp.bfloat16
     rows = (1 << 24) // fused_adam.LANE
     if name == "flash_attention":
@@ -63,6 +66,14 @@ def _kernel_case(name, chip):
                 [_sds((1, 32, 4096, 128), bf16, chip),
                  _sds((1, 4, 4096, 128), bf16, chip),
                  _sds((1, 4, 4096, 128), bf16, chip)])
+    if name == "flash_attention_bwd":
+        def loss(q, k, v):
+            o = flash_attention.flash_attention(q, k, v, interpret=False)
+            return jnp.sum(o.astype(f32))
+        return (jax.grad(loss, argnums=(0, 1, 2)),
+                [_sds((3, 14, 4096, 64), bf16, chip),
+                 _sds((3, 2, 4096, 64), bf16, chip),
+                 _sds((3, 2, 4096, 64), bf16, chip)])
     if name == "rmsnorm":
         return (lambda x, w: rmsnorm.rmsnorm_2d(x, w, interpret=False),
                 [_sds((32768, 2048), bf16, chip), _sds((2048,), bf16, chip)])
@@ -75,8 +86,8 @@ def _kernel_case(name, chip):
             [_sds((rows, dgc_topk.LANE), f32, chip), _sds((1,), f32, chip)])
 
 
-@pytest.mark.parametrize("name", ["flash_attention", "rmsnorm",
-                                  "fused_adam", "dgc_topk"])
+@pytest.mark.parametrize("name", ["flash_attention", "flash_attention_bwd",
+                                  "rmsnorm", "fused_adam", "dgc_topk"])
 def test_kernel_compiles_to_tpu_custom_call(one_chip, name):
     fn, args = _kernel_case(name, one_chip)
     text = jax.jit(fn).lower(*args).compile().as_text()
@@ -100,3 +111,28 @@ def test_full_width_train_step_fits_one_chip(one_chip):
     assert len(extract_graph(module).lane_tasks(DEVICE_STREAM)) > 1000
     six_nt = 6 * count_params(cfg) * 2 * 2048
     assert six_nt <= aggregate_costs(module)["flops"] <= 2 * six_nt
+
+
+def test_training_attention_on_four_chips_gathers_no_activation(topo,
+                                                                monkeypatch):
+    """On a (data=4, model=1) mesh, the kernel runs per shard inside
+    ``shard_map``: its forward and backward compile to TPU kernels and no
+    all-gather of q, k, v or their gradients appears."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    import numpy as np
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+    rows = NamedSharding(mesh, P("data"))
+    bf16 = jnp.bfloat16
+    q = _sds((8, 1024, 12, 64), bf16, rows)
+    kv = _sds((8, 1024, 4, 64), bf16, rows)
+
+    def loss(q, k, v):
+        with jax.named_scope("attn"):
+            o = attention.attend(q, k, v)
+        return jnp.sum(o.astype(jnp.float32))
+    with jax.set_mesh(mesh):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, kv, kv).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert " all-gather" not in text and "all-gather-start" not in text
