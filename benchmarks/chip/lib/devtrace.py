@@ -7,8 +7,11 @@ slice per executed HLO op; a ``while`` slice contains its body's ops) and
 a ``Steps`` line (one slice per annotated step).  The traced window is the
 span of the ``Steps`` line; busy time is the union of the op slices in it;
 a scope's time is the summed duration of the leaf op slices whose
-``tf_op`` name stack holds that scope (``attn``, ``mlp``, ``update``...),
-forward, backward and recompute alike.
+``tf_op`` name stack holds that scope, forward, backward and recompute
+alike.  The scopes are the program's ``jax.named_scope`` names: ``attn``,
+``mlp``, ``loss``, ``update``, ``norm``, ``embed``, ``unembed``,
+``frontend``, and the two mechanisms other than attention, ``ssm`` (the
+Mamba-2 SSD scan) and ``moe`` (the expert layer).
 
 This is deliberately separate from ``repro.traceio``, which is code under
 test.
@@ -24,7 +27,7 @@ import os
 import re
 
 SCOPES = ("attn", "mlp", "loss", "update", "norm", "embed",
-          "unembed", "frontend")
+          "unembed", "frontend", "ssm", "moe")
 
 
 def trace_file(trace_dir: str) -> str:

@@ -6,9 +6,29 @@ dtypes come from the program's abstract init (``jax.eval_shape``), every
 value from the seed and the configuration file's ``init`` rules.  Neither
 side makes its own weights, so the reference takes nothing the program
 has made.
+
+A rule is a list, looked up by the leaf's dotted name, then by its last
+component, then ``"*"``:
+
+- ``["const", c]``: every element ``c``;
+- ``["normal", std]``: N(0, std²);
+- ``["uniform", lo, hi]``: U(lo, hi);
+- ``["log_uniform", lo, hi]``: exp(U(ln lo, ln hi)).
+
+A ``uniform`` or ``log_uniform`` rule may end in a transform of the draw:
+``"log"`` (ln x) or ``"softplus_inverse"`` (x + ln(−expm1(−x)), so that
+softplus of the leaf is the draw).  The published Mamba-2 init (the
+``mamba_ssm`` ``Mamba2`` module) is then ``"A_log": ["uniform", 1, 16,
+"log"]``, ``"dt_bias": ["log_uniform", 0.001, 0.1, "softplus_inverse"]``,
+``"D": ["const", 1.0]`` and the depthwise conv's weight and bias
+``["uniform", -0.5, 0.5]`` (PyTorch's default for fan-in 4).  The module
+also clamps dt below at 1e-4, which never binds above 0.001, so no rule
+carries it.
 """
 
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -39,12 +59,29 @@ def _rule(rules: dict, name: str):
     return rules.get(name, rules.get(last, rules["*"]))
 
 
+_TRANSFORMS = {
+    "log": jnp.log,
+    "softplus_inverse": lambda x: x + jnp.log(-jnp.expm1(-x)),
+}
+
+
 def _draw(key, shape, rule):
     kind = rule[0]
     if kind == "const":
         return jnp.full(shape, rule[1], jnp.float32)
     if kind == "normal":
         return jax.random.normal(key, shape, jnp.float32) * rule[1]
+    if kind in ("uniform", "log_uniform") and len(rule) in (3, 4) \
+            and all(t in _TRANSFORMS for t in rule[3:]):
+        lo, hi = rule[1], rule[2]
+        if kind == "uniform":
+            x = jax.random.uniform(key, shape, jnp.float32, lo, hi)
+        else:
+            x = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
+                                           math.log(lo), math.log(hi)))
+        for t in rule[3:]:
+            x = _TRANSFORMS[t](x)
+        return x
     raise ValueError(f"unknown init rule {rule!r}")
 
 

@@ -12,8 +12,9 @@ matmuls) and the fault "half of the batch left out, the mean taken over
 the rest"; each gives a reading that the limits must stay below.  A step
 that returns its state unchanged reads 1 on the change by construction.
 
-``--small`` shrinks the configuration to a CPU-sized model, for a
-rehearsal without the chip.  One JSON line per reading, then a summary.
+``--small`` shrinks the configuration to the CPU-sized model of its
+file's ``small`` block, for a rehearsal without the chip.  One JSON line
+per reading, then a summary.
 """
 
 from __future__ import annotations
@@ -31,9 +32,14 @@ sys.path.insert(0, HERE)
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(HERE)),
                                 "src"))
 
-SMALL = {"internvl2-1b": ({"n_layers": 2, "d_model": 64, "n_heads": 4,
-                           "n_kv_heads": 2, "d_ff": 128, "vocab": 512,
-                           "n_patches": 4}, {"batch": 3, "seq_len": 64})}
+
+def shrink(cfg: dict) -> dict:
+    """The configuration at the sizes of its file's ``small`` block: the
+    ``model`` and ``train`` keys it names replaced, every other kept."""
+    out = copy.deepcopy(cfg)
+    out["model"].update(cfg["small"]["model"])
+    out["train"].update(cfg["small"]["train"])
+    return out
 
 
 def main(argv=None) -> int:
@@ -49,9 +55,7 @@ def main(argv=None) -> int:
     cfg, ref = bench.config_files(args.config)
     mix = bench.mix_file(args.traffic)
     if args.small:
-        cfg = copy.deepcopy(cfg)
-        cfg["model"].update(SMALL[args.config][0])
-        cfg["train"] = SMALL[args.config][1]
+        cfg = shrink(cfg)
     else:
         bench.device_info(1)
         bench.use_compile_cache()
