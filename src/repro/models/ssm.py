@@ -1,5 +1,12 @@
 """Mamba-2 (SSD — state-space duality) block: chunked train scan + O(1) decode.
 
+The block is the published ``mamba_ssm`` ``Mamba2`` mixer (Dao & Gu,
+arXiv:2405.21060): projections of the input to z, x, B, C and dt; one
+depthwise causal conv with bias over the concatenated x, B and C, then
+SiLU on all three; the SSD recurrence h_t = exp(dt_t·A)·h_{t-1} +
+dt_t·B_t ⊗ x_t, y_t = C_t·h_t + D·x_t per head; the gated RMSNorm of
+y·SiLU(z); the out-projection.
+
 TPU adaptation (DESIGN.md §2): the CUDA Mamba kernel is a fused warp-level
 scan; the TPU-native formulation is the SSD *chunked* algorithm — quadratic
 attention-like compute inside fixed-size chunks (MXU-friendly (Q,Q) matmuls)
@@ -7,19 +14,30 @@ with a sequential inter-chunk state recurrence (``lax.scan``).  Decode carries
 (conv window, SSM state) and is O(1) per token — which is why mamba2 runs the
 ``long_500k`` cell that dense-attention archs skip.
 
-Simplifications vs the reference CUDA implementation (documented):
+Numerics follow the published kernels: the conv's sums and SiLU, the
+carried state, the log-decay sums, every decay factor and the norm's gate
+are f32; the intra-chunk, state and read-out matmuls take operands in the
+model's dtype and accumulate in f32.  A decay is only ever ``exp`` of a
+sum of non-positive log-decays: the upper triangle of the intra-chunk
+segment sums is masked to −inf *before* ``exp``, so no positive sum
+overflows (its backward would be 0·inf).
+
+Simplifications vs the reference CUDA implementation:
   * n_groups = 1 (B/C shared across heads),
-  * the short causal conv applies to the x branch only,
-  * gate normalization is RMSNorm(y * silu(z)).
+  * the in-projection is held as five matrices (z, x, B, C, dt): the
+    published one split by columns,
+  * the program's own init keeps A = −1 and dt_bias = 0 (the published
+    A ~ U(1, 16), dt ~ logU(1e-3, 0.1) are the benchmark's init rules).
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.sharding import shard, use_weight
 from .paramdecl import normal_param, zeros_param, ones_param, split_keys
 from .layers import rmsnorm_init, rmsnorm
@@ -28,11 +46,13 @@ Params = Dict[str, Any]
 
 CONV_K = 4         # short depthwise conv kernel width
 HEAD_P = 64        # SSD head dim
+F32 = jnp.float32
 
 
 def mamba2_init(key, d: int, d_state: int, dtype, *, expand: int = 2) -> Params:
     d_inner = expand * d
     n_heads = d_inner // HEAD_P
+    conv_dim = d_inner + 2 * d_state          # x, B and C
     k1, k2, k3, k4, k5, k6, k7 = split_keys(key, 7)
     return {
         "wz": normal_param(k1, (d, d_inner), dtype, "fsdp", "ff_mega"),
@@ -40,134 +60,145 @@ def mamba2_init(key, d: int, d_state: int, dtype, *, expand: int = 2) -> Params:
         "wB": normal_param(k3, (d, d_state), dtype, "fsdp", "out_fsdp"),
         "wC": normal_param(k4, (d, d_state), dtype, "fsdp", "out_fsdp"),
         "w_dt": normal_param(k5, (d, n_heads), dtype, "fsdp", "heads"),
-        "dt_bias": zeros_param(k5, (n_heads,), jnp.float32, "heads"),
-        "A_log": zeros_param(k5, (n_heads,), jnp.float32, "heads"),
-        "D": ones_param(k5, (n_heads,), jnp.float32, "heads"),
-        "conv": normal_param(k6, (CONV_K, d_inner), dtype, None, "heads",
+        "dt_bias": zeros_param(k5, (n_heads,), F32, "heads"),
+        "A_log": zeros_param(k5, (n_heads,), F32, "heads"),
+        "D": ones_param(k5, (n_heads,), F32, "heads"),
+        "conv": normal_param(k6, (CONV_K, conv_dim), dtype, None, None,
                              scale=0.5),
+        "conv_b": zeros_param(k6, (conv_dim,), dtype, None),
         "norm": rmsnorm_init(k7, d_inner, dtype),
         "w_out": normal_param(k7, (d_inner, d), dtype, "heads", "out_fsdp"),
     }
 
 
-def _causal_conv(x: jax.Array, kernel: jax.Array) -> jax.Array:
-    """Depthwise causal conv via shifted adds.  x: (B,S,D); kernel: (K,D)."""
-    out = x * kernel[-1]
+def _causal_conv(x: jax.Array, kernel: jax.Array, bias: jax.Array
+                 ) -> jax.Array:
+    """Depthwise causal conv with bias via shifted adds.  x: (B,S,D);
+    kernel: (K,D), its last row on the current position; bias: (D,).
+    Sums in x's dtype (the caller passes f32, as the published kernel
+    accumulates)."""
+    out = x * kernel[-1] + bias
     for i in range(1, CONV_K):
         shifted = jnp.pad(x, ((0, 0), (i, 0), (0, 0)))[:, :-i or None, :]
         out = out + shifted * kernel[CONV_K - 1 - i]
     return out
 
 
+def _gated_norm(p: Params, y: jax.Array, z: jax.Array) -> jax.Array:
+    """RMSNorm(y · SiLU(z)), the gate taken in f32; in y's dtype."""
+    g = y.astype(F32) * jax.nn.silu(z.astype(F32))
+    return rmsnorm(p, g).astype(y.dtype)
+
+
+def _ssd_chunk(D: jax.Array, state: jax.Array, inp):
+    """One chunk of the SSD scan.  state: (B,H,P,N) f32; inp: x (B,Q,H,P),
+    B and C (B,Q,N) in the model's dtype, dt and dt·A (B,Q,H) in f32.
+    Returns the state after the chunk and y (B,Q,H,P) in x's dtype."""
+    xq, bq, cq, dtq, daq = inp
+    Q = xq.shape[1]
+    cum = jnp.cumsum(daq, axis=1)                          # (B,Q,H), falling
+    # decay from j to i >= j: exp(cum_i - cum_j) <= 1; above the diagonal
+    # the sum is positive and is masked before exp, never after
+    causal = jnp.tril(jnp.ones((Q, Q), bool))[None, :, :, None]
+    seg = jnp.where(causal, cum[:, :, None, :] - cum[:, None, :, :], -jnp.inf)
+    cb = jnp.einsum("bin,bjn->bij", cq, bq, preferred_element_type=F32)
+    M = (cb[..., None] * jnp.exp(seg) * dtq[:, None]).astype(xq.dtype)
+    y = jnp.einsum("bijh,bjhp->bihp", M, xq, preferred_element_type=F32)
+    # read-out of the state carried in from earlier chunks
+    y = y + jnp.einsum("bin,bhpn->bihp", cq, state.astype(cq.dtype),
+                       preferred_element_type=F32) * jnp.exp(cum)[..., None]
+    y = y + xq.astype(F32) * D[:, None]
+    # the state after the chunk: decay j..end times dt_j on each input
+    w = jnp.exp(cum[:, -1:] - cum) * dtq                   # (B,Q,H)
+    xs = (xq * w[..., None]).astype(xq.dtype)
+    state = state * jnp.exp(cum[:, -1])[:, :, None, None] + jnp.einsum(
+        "bjn,bjhp->bhpn", bq, xs, preferred_element_type=F32)
+    return state, y.astype(xq.dtype)
+
+
 def mamba2_forward(p: Params, x: jax.Array, *, chunk: int = 128,
                    return_state: bool = False):
-    """x: (B, S, d) -> (B, S, d) via the SSD chunked algorithm."""
+    """x: (B, S, d) -> (B, S, d) via the SSD chunked algorithm.  With
+    ``return_state`` also the decode cache: the last K-1 conv inputs and
+    the f32 state."""
     with jax.named_scope("ssm"):
         B_, S, d = x.shape
         d_inner = p["wx"].shape[-1]
         H = d_inner // HEAD_P
         N = p["wB"].shape[-1]
-        z = jnp.einsum("bsd,de->bse", x, use_weight(p["wz"], None, "heads"))
-        xb_pre = jnp.einsum("bsd,de->bse", x,
-                            use_weight(p["wx"], None, "heads"))
-        xb = jax.nn.silu(_causal_conv(xb_pre, p["conv"]))
-        Bm = jnp.einsum("bsd,dn->bsn", x, p["wB"])
-        Cm = jnp.einsum("bsd,dn->bsn", x, p["wC"])
-        dt = jax.nn.softplus(
-            jnp.einsum("bsd,dh->bsh", x, p["w_dt"]).astype(jnp.float32)
-            + p["dt_bias"])
-        A = -jnp.exp(p["A_log"])                             # (H,), negative
-        dA = dt * A                                          # (B,S,H) log-decay
-
-        X = xb.reshape(B_, S, H, HEAD_P)
-        Xe = (X * dt[..., None].astype(X.dtype))             # dt-scaled input
-
         chunk = min(chunk, S)
         nc = (S + chunk - 1) // chunk
         pad = nc * chunk - S
+        with obs.span("ssm.dispatch", x=list(x.shape), chunk=chunk,
+                      chunks=nc, pad=pad, state_dtype="float32",
+                      return_state=return_state):
+            pass
+        z = jnp.einsum("bsd,de->bse", x, use_weight(p["wz"], None, "heads"))
+        xbc_pre = jnp.concatenate([
+            jnp.einsum("bsd,de->bse", x, use_weight(p["wx"], None, "heads")),
+            jnp.einsum("bsd,dn->bsn", x, p["wB"]),
+            jnp.einsum("bsd,dn->bsn", x, p["wC"])], axis=-1)
+        xbc = jax.nn.silu(_causal_conv(
+            xbc_pre.astype(F32), p["conv"].astype(F32),
+            p["conv_b"].astype(F32))).astype(x.dtype)
+        X = xbc[..., :d_inner].reshape(B_, S, H, HEAD_P)
+        Bm, Cm = xbc[..., d_inner:d_inner + N], xbc[..., d_inner + N:]
+        dt = jax.nn.softplus(
+            jnp.einsum("bsd,dh->bsh", x, p["w_dt"]).astype(F32)
+            + p["dt_bias"])                                  # (B,S,H)
+        dA = dt * -jnp.exp(p["A_log"])                       # log-decay <= 0
+
         if pad:
             X = jnp.pad(X, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            Xe = jnp.pad(Xe, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            Bm = jnp.pad(Bm, ((0, 0), (0, pad), (0, 0)))
-            Cm = jnp.pad(Cm, ((0, 0), (0, pad), (0, 0)))
-            dA = jnp.pad(dA, ((0, 0), (0, pad), (0, 0)))
+            Bm, Cm, dt, dA = (jnp.pad(t, ((0, 0), (0, pad), (0, 0)))
+                              for t in (Bm, Cm, dt, dA))
 
         def to_chunks(t):
-            return t.reshape((B_, nc, chunk) + t.shape[2:]).transpose(
-                (1, 0, 2) + tuple(range(3, t.ndim + 1)))
+            return t.reshape((B_, nc, chunk) + t.shape[2:]).swapaxes(0, 1)
 
-        Xc, Xec, Bc, Cc = map(to_chunks, (X, Xe, Bm, Cm))
-        dAc = to_chunks(dA)
-
-        def body(state, inp):
-            xq, xe, bq, cq, da = inp        # (B,Q,H,P),(B,Q,H,P),(B,Q,N)x2,(B,Q,H)
-            cum = jnp.cumsum(da, axis=1)                       # (B,Q,H)
-            # intra-chunk (attention-like) term
-            seg = cum[:, :, None, :] - cum[:, None, :, :]      # (B,Q,Q,H) i,j
-            Q = xq.shape[1]
-            causal = jnp.tril(jnp.ones((Q, Q), bool))
-            L = jnp.where(causal[None, :, :, None], jnp.exp(seg), 0.0)
-            scores = jnp.einsum("bin,bjn->bij", cq.astype(jnp.float32),
-                                bq.astype(jnp.float32))
-            M = (scores[..., None] * L).astype(xq.dtype)       # (B,Q,Q,H)
-            y_intra = jnp.einsum("bijh,bjhp->bihp", M, xe)
-            # inter-chunk term from carried state
-            decay_in = jnp.exp(cum).astype(xq.dtype)           # (B,Q,H)
-            y_inter = jnp.einsum("bin,bhpn->bihp", cq, state) \
-                * decay_in[..., None]
-            # state update
-            a_all = jnp.exp(cum[:, -1])                        # (B,H)
-            w = jnp.exp(cum[:, -1:, :] - cum).astype(xq.dtype)  # decay j..end
-            state = state * a_all[:, :, None, None].astype(state.dtype) \
-                + jnp.einsum("bjn,bjhp,bjh->bhpn", bq, xe, w)
-            y = y_intra + y_inter + xq * p["D"][None, None, :, None].astype(
-                xq.dtype)
-            return state, y
-
-        state0 = jnp.zeros((B_, H, HEAD_P, N), x.dtype)
-        state_f, Yc = jax.lax.scan(body, state0, (Xc, Xec, Bc, Cc, dAc))
-        Y = Yc.transpose(1, 0, 2, 3, 4).reshape(B_, nc * chunk, H, HEAD_P)
-        Y = Y[:, :S].reshape(B_, S, d_inner)
-        Y = rmsnorm(p["norm"], Y * jax.nn.silu(z))
+        state0 = jnp.zeros((B_, H, HEAD_P, N), F32)
+        state_f, Yc = jax.lax.scan(
+            lambda s, inp: _ssd_chunk(p["D"], s, inp), state0,
+            tuple(map(to_chunks, (X, Bm, Cm, dt, dA))))
+        Y = Yc.swapaxes(0, 1).reshape(B_, nc * chunk, d_inner)[:, :S]
+        Y = _gated_norm(p["norm"], Y, z)
         out = jnp.einsum("bse,ed->bsd", Y,
                          use_weight(p["w_out"], "heads", None))
         out = shard(out, "batch", None, None)
         if not return_state:
             return out
-        tail = jnp.pad(xb_pre, ((0, 0), (CONV_K - 1, 0), (0, 0)))[
+        tail = jnp.pad(xbc_pre, ((0, 0), (CONV_K - 1, 0), (0, 0)))[
             :, S:S + CONV_K - 1, :]
         return out, {"conv": tail, "state": state_f}
 
 
 def mamba2_decode(p: Params, x: jax.Array, cache: Params
                   ) -> Tuple[jax.Array, Params]:
-    """One-token step.  x: (B, 1, d); cache: {"conv": (B, K-1, d_inner),
-    "state": (B, H, P, N)}.  O(1) in sequence length."""
+    """One-token step.  x: (B, 1, d); cache: {"conv": (B, K-1, E+2N),
+    "state": (B, H, P, N) f32}.  O(1) in sequence length."""
     with jax.named_scope("ssm"):
         B_ = x.shape[0]
         d_inner = p["wx"].shape[-1]
         H = d_inner // HEAD_P
-        z = jnp.einsum("bsd,de->bse", x, p["wz"])[:, 0]
-        xb = jnp.einsum("bsd,de->bse", x, p["wx"])[:, 0]       # (B, d_inner)
-        window = jnp.concatenate([cache["conv"], xb[:, None, :]], axis=1)
-        conv_out = jnp.einsum("bke,ke->be", window, p["conv"].astype(window.dtype))
-        xb = jax.nn.silu(conv_out)
-        Bt = jnp.einsum("bsd,dn->bsn", x, p["wB"])[:, 0]
-        Ct = jnp.einsum("bsd,dn->bsn", x, p["wC"])[:, 0]
-        dt = jax.nn.softplus(
-            jnp.einsum("bsd,dh->bsh", x, p["w_dt"]).astype(jnp.float32)[:, 0]
-            + p["dt_bias"])                                    # (B,H)
-        A = -jnp.exp(p["A_log"])
-        a = jnp.exp(dt * A).astype(cache["state"].dtype)       # (B,H)
-        X = xb.reshape(B_, H, HEAD_P)
-        Xe = X * dt[..., None].astype(X.dtype)
+        N = p["wB"].shape[-1]
+        xt = x[:, 0]
+        z = xt @ p["wz"]
+        xbc = jnp.concatenate([xt @ p["wx"], xt @ p["wB"], xt @ p["wC"]],
+                              axis=-1)                       # (B, E+2N)
+        window = jnp.concatenate([cache["conv"], xbc[:, None, :]], axis=1)
+        xbc = jax.nn.silu(jnp.einsum("bkc,kc->bc", window.astype(F32),
+                                     p["conv"].astype(F32))
+                          + p["conv_b"].astype(F32)).astype(x.dtype)
+        X = xbc[:, :d_inner].reshape(B_, H, HEAD_P).astype(F32)
+        Bt = xbc[:, d_inner:d_inner + N].astype(F32)
+        Ct = xbc[:, d_inner + N:].astype(F32)
+        dt = jax.nn.softplus((xt @ p["w_dt"]).astype(F32) + p["dt_bias"])
+        a = jnp.exp(dt * -jnp.exp(p["A_log"]))                 # (B,H)
         state = cache["state"] * a[:, :, None, None] \
-            + jnp.einsum("bn,bhp->bhpn", Bt, Xe)
-        y = jnp.einsum("bn,bhpn->bhp", Ct, state) \
-            + X * p["D"][None, :, None].astype(X.dtype)
-        y = y.reshape(B_, d_inner)
-        y = rmsnorm(p["norm"], y * jax.nn.silu(z))
-        out = jnp.einsum("be,ed->bd", y, p["w_out"])[:, None, :]
+            + jnp.einsum("bn,bhp->bhpn", Bt, X * dt[..., None])
+        y = jnp.einsum("bn,bhpn->bhp", Ct, state) + X * p["D"][:, None]
+        y = _gated_norm(p["norm"], y.reshape(B_, d_inner).astype(x.dtype), z)
+        out = (y @ p["w_out"])[:, None, :]
         return out, {"conv": window[:, 1:], "state": state}
 
 
@@ -177,8 +208,8 @@ def mamba2_cache_spec(batch: int, d: int, d_state: int, dtype, *,
     d_inner = expand * d
     H = d_inner // HEAD_P
     return {
-        "conv": SpecLeaf((batch, CONV_K - 1, d_inner), jnp.dtype(dtype),
-                         ("batch", None, "heads")),
-        "state": SpecLeaf((batch, H, HEAD_P, d_state), jnp.dtype(dtype),
+        "conv": SpecLeaf((batch, CONV_K - 1, d_inner + 2 * d_state),
+                         jnp.dtype(dtype), ("batch", None, None)),
+        "state": SpecLeaf((batch, H, HEAD_P, d_state), jnp.dtype(F32),
                           ("batch", "heads", None, None)),
     }
