@@ -12,7 +12,9 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from . import causal_conv as _cc
 from . import flash_attention as _fa
+from . import ssd_chunk as _sc
 from . import fused_adam as _ad
 from . import rmsnorm as _rn
 from . import dgc_topk as _dg
@@ -44,6 +46,23 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     out = _fa.flash_attention(qp, kp, vp, causal=causal, kv_len=S,
                               interpret=_interpret())
     return out[:, :, :S]
+
+
+# ------------------------------------------------------------ chunked ssd
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def ssd(x: jax.Array, b: jax.Array, c: jax.Array, dt: jax.Array,
+        cum: jax.Array, d: jax.Array, *, chunk: int):
+    """The chunked SSD (``ssd_chunk.ssd``): x (B, S, E), b/c (B, S, N),
+    dt/cum (B, H, S) f32, d (H,) -> y (B, S, E), final state (B, N, E)."""
+    return _sc.ssd(x, b, c, dt, cum, d, chunk=chunk, interpret=_interpret())
+
+
+# ------------------------------------------------------- causal conv silu
+@jax.jit
+def conv_silu(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
+    """SiLU(causal depthwise conv of x + b) (``causal_conv.conv_silu``): x
+    (B, S, C) with S a multiple of 8, w (K, C), b (C,)."""
+    return _cc.conv_silu(x, w, b, interpret=_interpret())
 
 
 # ------------------------------------------------------------- fused adam

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from repro import obs
 from repro.kernels import ops
 from repro.kernels.flash_attention import backward_fits
-from repro.sharding import active_rules, current_mesh, shard, use_weight
+from repro.sharding import kernel_spec, shard, use_weight
 from .paramdecl import normal_param, zeros_param, ones_param, split_keys
 
 Params = Dict[str, Any]
@@ -125,12 +125,10 @@ def _mesh_specs(q: jax.Array, k: jax.Array):
     """(q spec, k spec) of the operands on the active mesh of more than one
     device, which the kernel runs under in ``shard_map``; ``None``
     without one."""
-    m = current_mesh()
-    if m is None or m.size == 1:
+    qs = kernel_spec(q.shape, "batch", None, "heads", None)
+    if qs is None:
         return None
-    rules = active_rules()
-    return (rules.spec("batch", None, "heads", None, dim_sizes=q.shape),
-            rules.spec("batch", None, "heads", None, dim_sizes=k.shape))
+    return qs, kernel_spec(k.shape, "batch", None, "heads", None)
 
 
 def _scan_reason(q, k, v, window, q_offset) -> Optional[str]:

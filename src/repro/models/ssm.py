@@ -10,9 +10,27 @@ y·SiLU(z); the out-projection.
 TPU adaptation (DESIGN.md §2): the CUDA Mamba kernel is a fused warp-level
 scan; the TPU-native formulation is the SSD *chunked* algorithm — quadratic
 attention-like compute inside fixed-size chunks (MXU-friendly (Q,Q) matmuls)
-with a sequential inter-chunk state recurrence (``lax.scan``).  Decode carries
-(conv window, SSM state) and is O(1) per token — which is why mamba2 runs the
-``long_500k`` cell that dense-attention archs skip.
+with a sequential inter-chunk state recurrence.  Two paths compute it, the
+same math in the same precisions:
+
+  * on a TPU, where the chunk is one the kernels tile (128 or 256) and
+    the heads pair into 128 lanes, ``kernels/ssd_chunk.py``: Pallas kernels
+    with their own backward for each chunk's state, the recurrence over
+    chunks, and the diagonal block with the read-out and D·x over (batch,
+    chunk, head block), so no (Q,Q) tile leaves VMEM; the in-chunk
+    ``cumsum`` of the log-decays stays in XLA;
+  * elsewhere (the CPU, other chunks or widths, heads split across a mesh)
+    a ``lax.scan`` of ``_ssd_chunk`` over the chunks, which is also the
+    kernels' oracle in the tests.
+
+The xBC conv and its SiLU choose apart from the SSD, as they tile any
+length and width: on a TPU ``kernels/causal_conv.py``, one pass each way,
+elsewhere (and where heads are split across a mesh) the f32 shifted adds
+of ``_causal_conv``.  ``mamba2_forward`` chooses from what it is given and
+records both choices (``ssm.dispatch``: ``path`` and ``conv``, and for
+each path kept in XLA its reason).  Decode carries (conv window, SSM
+state) and is O(1) per token — which is why mamba2 runs the ``long_500k``
+cell that dense-attention archs skip.
 
 Numerics follow the published kernels: the conv's sums and SiLU, the
 carried state, the log-decay sums, every decay factor and the norm's gate
@@ -32,13 +50,17 @@ Simplifications vs the reference CUDA implementation:
 
 from __future__ import annotations
 
-from typing import Any, Dict, Tuple
+import functools
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 from repro import obs
-from repro.sharding import shard, use_weight
+from repro.kernels import ops
+from repro.kernels.ssd_chunk import CHUNKS, head_block
+from repro.sharding import kernel_spec, shard, use_weight
 from .paramdecl import normal_param, zeros_param, ones_param, split_keys
 from .layers import rmsnorm_init, rmsnorm
 
@@ -116,11 +138,110 @@ def _ssd_chunk(D: jax.Array, state: jax.Array, inp):
     return state, y.astype(xq.dtype)
 
 
+def _ssd_scan(D: jax.Array, X, Bm, Cm, dt, dA, chunk: int):
+    """The chunked SSD as a ``lax.scan`` of ``_ssd_chunk`` over the chunks:
+    the CPU path and the kernel's oracle.  X (B,S,H,P), Bm/Cm (B,S,N),
+    dt/dA (B,S,H) f32, S a multiple of ``chunk``.  Returns the final f32
+    state (B,H,P,N) and y (B,S,H·P) in X's dtype."""
+    B_, S, H, P = X.shape
+    nc = S // chunk
+
+    def to_chunks(t):
+        return t.reshape((B_, nc, chunk) + t.shape[2:]).swapaxes(0, 1)
+
+    state0 = jnp.zeros((B_, H, P, Bm.shape[-1]), F32)
+    state_f, Yc = jax.lax.scan(
+        lambda s, inp: _ssd_chunk(D, s, inp), state0,
+        tuple(map(to_chunks, (X, Bm, Cm, dt, dA))))
+    return state_f, Yc.swapaxes(0, 1).reshape(B_, S, H * P)
+
+
+def _ssd_pallas(D: jax.Array, X, Bm, Cm, dt, dA, chunk: int):
+    """The chunked SSD through the Pallas kernels of
+    ``kernels/ssd_chunk.py``, from the in-chunk ``cumsum`` of dA.  Takes
+    and returns what ``_ssd_scan`` does."""
+    B_, S, H, P = X.shape
+    N = Bm.shape[-1]
+    dt, dA = dt.transpose(0, 2, 1), dA.transpose(0, 2, 1)   # (B,H,S)
+    cum = jnp.cumsum(dA.reshape(B_, H, S // chunk, chunk), axis=-1)
+    y, state = _per_shard(functools.partial(ops.ssd, chunk=chunk),
+                          X.reshape(B_, S, H * P), Bm, Cm, dt,
+                          cum.reshape(B_, H, S), D)
+    return state.reshape(B_, N, H, P).transpose(0, 2, 3, 1), y
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _x_spec(shape) -> Optional[PartitionSpec]:
+    """The spec of x (B, S, E) on a mesh of more than one device, under
+    which the kernels run per shard; ``None`` without one."""
+    return kernel_spec(shape, "batch", None, "heads")
+
+
+def _conv_reason(shape) -> Optional[str]:
+    """Why ``mamba2_forward`` keeps the XLA conv, or ``None`` where the
+    conv kernel runs; ``shape`` is x's (B, S, E).  The kernel takes any
+    length (its rows are padded to 8) and width."""
+    if not _on_tpu():
+        return "backend"
+    spec = _x_spec(shape)
+    if spec is not None and spec[2] is not None:
+        return "heads_sharding"    # the kernel's rows hold every channel
+    return None
+
+
+def _scan_reason(shape, H: int, chunk: int) -> Optional[str]:
+    """Why ``mamba2_forward`` keeps the scan, or ``None`` where the
+    kernels run; ``shape`` is x's (B, S, E)."""
+    if not _on_tpu():
+        return "backend"
+    if chunk not in CHUNKS:
+        return "chunk"
+    if head_block(H, HEAD_P) is None:
+        return "width"
+    spec = _x_spec(shape)
+    if spec is not None and spec[2] is not None:
+        return "heads_sharding"    # a head block would cross shards
+    return None
+
+
+def _per_shard(run, *args):
+    """``run`` on the operands as they are, or per batch shard under a mesh
+    of more than one device: every operand but the last is batch-leading,
+    the last is replicated."""
+    spec = _x_spec(args[0].shape)
+    if spec is None:
+        return run(*args)
+    rows = PartitionSpec(spec[0])
+    return jax.shard_map(run, in_specs=(rows,) * (len(args) - 1)
+                         + (PartitionSpec(),), out_specs=rows,
+                         check_vma=False)(*args)
+
+
+def _conv_silu(xbc_pre: jax.Array, w: jax.Array, b: jax.Array,
+               kernel: bool) -> jax.Array:
+    """SiLU of the causal xBC conv with bias, in xbc_pre's dtype: the
+    Pallas kernel (rows padded to a multiple of 8) or the f32 shifted adds
+    of ``_causal_conv``."""
+    if not kernel:
+        return jax.nn.silu(_causal_conv(
+            xbc_pre.astype(F32), w.astype(F32),
+            b.astype(F32))).astype(xbc_pre.dtype)
+    S = xbc_pre.shape[1]
+    x = jnp.pad(xbc_pre, ((0, 0), (0, -S % 8), (0, 0)))
+    return _per_shard(lambda x, wb: ops.conv_silu(x, *wb), x, (w, b))[:, :S]
+
+
 def mamba2_forward(p: Params, x: jax.Array, *, chunk: int = 128,
                    return_state: bool = False):
-    """x: (B, S, d) -> (B, S, d) via the SSD chunked algorithm.  With
-    ``return_state`` also the decode cache: the last K-1 conv inputs and
-    the f32 state."""
+    """x: (B, S, d) -> (B, S, d) via the SSD chunked algorithm: the Pallas
+    kernels where the backend and widths allow it, else the ``lax.scan``
+    of ``_ssd_chunk``, after the conv kernel or ``_causal_conv``; the
+    choices are recorded as ``ssm.dispatch`` when the caller is traced.
+    With ``return_state`` also the decode cache: the last K-1 conv inputs
+    and the f32 state."""
     with jax.named_scope("ssm"):
         B_, S, d = x.shape
         d_inner = p["wx"].shape[-1]
@@ -129,18 +250,26 @@ def mamba2_forward(p: Params, x: jax.Array, *, chunk: int = 128,
         chunk = min(chunk, S)
         nc = (S + chunk - 1) // chunk
         pad = nc * chunk - S
-        with obs.span("ssm.dispatch", x=list(x.shape), chunk=chunk,
-                      chunks=nc, pad=pad, state_dtype="float32",
-                      return_state=return_state):
+        reason = _scan_reason((B_, S, d_inner), H, chunk)
+        conv_reason = _conv_reason((B_, S, d_inner))
+        attrs = {"path": "scan" if reason else "pallas",
+                 "conv": "xla" if conv_reason else "pallas",
+                 "x": list(x.shape), "chunk": chunk, "chunks": nc,
+                 "pad": pad, "state_dtype": "float32",
+                 "return_state": return_state}
+        if reason:
+            attrs["reason"] = reason
+        if conv_reason:
+            attrs["conv_reason"] = conv_reason
+        with obs.span("ssm.dispatch", **attrs):
             pass
         z = jnp.einsum("bsd,de->bse", x, use_weight(p["wz"], None, "heads"))
         xbc_pre = jnp.concatenate([
             jnp.einsum("bsd,de->bse", x, use_weight(p["wx"], None, "heads")),
             jnp.einsum("bsd,dn->bsn", x, p["wB"]),
             jnp.einsum("bsd,dn->bsn", x, p["wC"])], axis=-1)
-        xbc = jax.nn.silu(_causal_conv(
-            xbc_pre.astype(F32), p["conv"].astype(F32),
-            p["conv_b"].astype(F32))).astype(x.dtype)
+        xbc = _conv_silu(xbc_pre, p["conv"], p["conv_b"],
+                         conv_reason is None)
         X = xbc[..., :d_inner].reshape(B_, S, H, HEAD_P)
         Bm, Cm = xbc[..., d_inner:d_inner + N], xbc[..., d_inner + N:]
         dt = jax.nn.softplus(
@@ -152,16 +281,9 @@ def mamba2_forward(p: Params, x: jax.Array, *, chunk: int = 128,
             X = jnp.pad(X, ((0, 0), (0, pad), (0, 0), (0, 0)))
             Bm, Cm, dt, dA = (jnp.pad(t, ((0, 0), (0, pad), (0, 0)))
                               for t in (Bm, Cm, dt, dA))
-
-        def to_chunks(t):
-            return t.reshape((B_, nc, chunk) + t.shape[2:]).swapaxes(0, 1)
-
-        state0 = jnp.zeros((B_, H, HEAD_P, N), F32)
-        state_f, Yc = jax.lax.scan(
-            lambda s, inp: _ssd_chunk(p["D"], s, inp), state0,
-            tuple(map(to_chunks, (X, Bm, Cm, dt, dA))))
-        Y = Yc.swapaxes(0, 1).reshape(B_, nc * chunk, d_inner)[:, :S]
-        Y = _gated_norm(p["norm"], Y, z)
+        ssd = _ssd_scan if reason else _ssd_pallas
+        state_f, Y = ssd(p["D"], X, Bm, Cm, dt, dA, chunk)
+        Y = _gated_norm(p["norm"], Y[:, :S], z)
         out = jnp.einsum("bse,ed->bsd", Y,
                          use_weight(p["w_out"], "heads", None))
         out = shard(out, "batch", None, None)

@@ -184,6 +184,16 @@ def logical_spec(*logical: LogicalAxis, rules: ShardingRules = DEFAULT_RULES,
     return rules.spec(*logical, dim_sizes=dim_sizes)
 
 
+def kernel_spec(shape: Sequence[int], *logical: LogicalAxis) -> Optional[P]:
+    """The spec by logical axes of an operand of ``shape`` on the active
+    mesh of more than one device, on which a Pallas kernel runs per shard
+    under ``shard_map``; ``None`` without such a mesh."""
+    m = current_mesh()
+    if m is None or m.size == 1:
+        return None
+    return active_rules().spec(*logical, dim_sizes=shape)
+
+
 def shard(x, *logical: LogicalAxis, rules: Optional[ShardingRules] = None):
     """``with_sharding_constraint`` by logical axes; no-op without a mesh."""
     m = current_mesh()

@@ -2,10 +2,11 @@
 
 The TPU compiler refuses what interpret mode and the CPU backend accept: a
 kernel tile the chip cannot lay out, or a step that does not fit the chip's
-memory.  These tests compile the four Pallas kernels at the widths the main
-path runs them (the flash kernel's backward too) and the full-width
-``tinyllama-1.1b`` training step of ``chip_smoke.py`` for one chip of a
-``v5e:2x2`` topology, and training attention on all four chips.
+memory.  These tests compile the Pallas kernels at the widths the main
+path runs them (the flash, SSD and conv kernels' backwards too) and the
+full-width ``tinyllama-1.1b`` training step of ``chip_smoke.py`` for one
+chip of a ``v5e:2x2`` topology, and training attention and the Mamba-2
+kernels on all four chips.
 """
 
 import os
@@ -18,7 +19,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.core import extract_graph, parse_hlo_module, aggregate_costs
 from repro.core.task import DEVICE_STREAM
-from repro.kernels import dgc_topk, flash_attention, fused_adam, rmsnorm
+from repro.kernels import (causal_conv, dgc_topk, flash_attention, fused_adam,
+                           rmsnorm, ssd_chunk)
 from repro.models.model import count_params
 from repro.models import attention
 from repro.train import Trainer, TrainerConfig
@@ -56,8 +58,10 @@ def _kernel_case(name, chip):
     """(kernel call with interpret=False, argument shapes) at the widths the
     main path uses: tinyllama's attention heads over 4096 tokens (head dim
     128), the backward at the ``internvl2-1b.train`` cell's widths (3 x 4096,
-    14 q / 2 kv heads of 64), rmsnorm over 32768 rows of 2048, fused Adam
-    and DGC over 2^24 parameters."""
+    14 q / 2 kv heads of 64), the chunked SSD and the xBC conv at the
+    ``mamba2-2.7b.train`` cell's (2 x 4096, 80 heads of 64, state 128,
+    chunk 256, 5376 conv channels), rmsnorm over 32768 rows of 2048, fused
+    Adam and DGC over 2^24 parameters."""
     f32, bf16 = jnp.float32, jnp.bfloat16
     rows = (1 << 24) // fused_adam.LANE
     if name == "flash_attention":
@@ -74,6 +78,26 @@ def _kernel_case(name, chip):
                 [_sds((3, 14, 4096, 64), bf16, chip),
                  _sds((3, 2, 4096, 64), bf16, chip),
                  _sds((3, 2, 4096, 64), bf16, chip)])
+    if name.startswith("ssd_chunk"):
+        def ssd(x, b, c, dt, cum, d):
+            y, state = ssd_chunk.ssd(x, b, c, dt, cum, d, chunk=256,
+                                     interpret=False)
+            return jnp.sum(y.astype(f32) ** 2) + jnp.sum(state)
+        fn = ssd if name == "ssd_chunk" else jax.grad(
+            ssd, argnums=(0, 1, 2, 3, 4, 5))
+        return (fn, [_sds((2, 4096, 80 * 64), bf16, chip),
+                     _sds((2, 4096, 128), bf16, chip),
+                     _sds((2, 4096, 128), bf16, chip),
+                     _sds((2, 80, 4096), f32, chip),
+                     _sds((2, 80, 4096), f32, chip),
+                     _sds((80,), f32, chip)])
+    if name == "causal_conv_bwd":
+        def conv(x, w, b):
+            y = causal_conv.conv_silu(x, w, b, interpret=False)
+            return jnp.sum(y.astype(f32) ** 2)
+        return (jax.grad(conv, argnums=(0, 1, 2)),
+                [_sds((2, 4096, 5376), bf16, chip),
+                 _sds((4, 5376), bf16, chip), _sds((5376,), bf16, chip)])
     if name == "rmsnorm":
         return (lambda x, w: rmsnorm.rmsnorm_2d(x, w, interpret=False),
                 [_sds((32768, 2048), bf16, chip), _sds((2048,), bf16, chip)])
@@ -87,7 +111,9 @@ def _kernel_case(name, chip):
 
 
 @pytest.mark.parametrize("name", ["flash_attention", "flash_attention_bwd",
-                                  "rmsnorm", "fused_adam", "dgc_topk"])
+                                  "ssd_chunk", "ssd_chunk_bwd",
+                                  "causal_conv_bwd", "rmsnorm", "fused_adam",
+                                  "dgc_topk"])
 def test_kernel_compiles_to_tpu_custom_call(one_chip, name):
     fn, args = _kernel_case(name, one_chip)
     text = jax.jit(fn).lower(*args).compile().as_text()
@@ -135,4 +161,38 @@ def test_training_attention_on_four_chips_gathers_no_activation(topo,
         text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
             q, kv, kv).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert " all-gather" not in text and "all-gather-start" not in text
+
+
+def test_chunked_ssd_on_four_chips_gathers_no_activation(topo, monkeypatch):
+    """On a (data=4, model=1) mesh, the SSD and xBC-conv kernels run per
+    batch shard inside ``shard_map``: the SSD's three forward and three
+    backward calls and the conv's two compile to TPU kernels and no
+    all-gather of an operand or a gradient appears."""
+    import functools
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    import numpy as np
+    from repro.kernels import ops
+    from repro.models import ssm
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+    rows = NamedSharding(mesh, P("data"))
+    whole = NamedSharding(mesh, P())
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    args = [_sds((8, 1024, 16 * 64), bf16, rows),
+            _sds((8, 1024, 128), bf16, rows), _sds((8, 1024, 128), bf16, rows),
+            _sds((8, 16, 1024), f32, rows), _sds((8, 16, 1024), f32, rows),
+            _sds((16,), f32, whole), _sds((4, 16 * 64), bf16, whole),
+            _sds((16 * 64,), bf16, whole)]
+
+    def loss(x, b, c, dt, cum, d, w, wb):
+        with jax.named_scope("ssm"):
+            x = ssm._conv_silu(x, w, wb, True)
+            y, state = ssm._per_shard(functools.partial(ops.ssd, chunk=256),
+                                      x, b, c, dt, cum, d)
+        return jnp.sum(y.astype(f32) ** 2) + jnp.sum(state)
+    with jax.set_mesh(mesh):
+        text = jax.jit(jax.grad(loss, argnums=tuple(range(8)))).lower(
+            *args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 8
     assert " all-gather" not in text and "all-gather-start" not in text
