@@ -8,8 +8,8 @@ random weights from a seed) for 5 steps of batch 2 x 2048 tokens through
 ``repro.train.Trainer``; capture steps 3-4 with ``jax.profiler``; import
 the capture with ``repro.traceio`` and check it against the measured step;
 predict from it with ``Scenario`` (``noop`` and ``amp``) and from the
-compiled step with ``trace_compiled``; run the four Pallas kernels compiled
-for the chip against their ``kernels/ref.py`` oracles.
+compiled step with ``trace_compiled``; run the flash attention kernel
+compiled for the chip against its ``kernels/ref.py`` oracle.
 
 Four chips: the same model and per-chip batch trained on a ``(data=4,
 model=1)`` mesh (FSDP, global batch 8 x 2048), captured, imported as four
@@ -198,50 +198,26 @@ def kernels() -> None:
     import jax.numpy as jnp
     from repro.kernels import ops, ref
 
-    def report(name, fn, args, kwargs, got, want, atol, rtol=0.0):
-        """|got - want| <= atol + rtol * |want| elementwise, as in
-        ``np.testing.assert_allclose``; bf16 outputs take one bf16 ulp of
-        relative slack (rtol 2^-7)."""
-        text = fn.lower(*args, **kwargs).compile().as_text()
-        err = over = float("-inf")
-        for g, w in zip(got, want):
-            diff = jnp.abs(g.astype(jnp.float32) - w.astype(jnp.float32))
-            err = max(err, float(jnp.max(diff)))
-            over = max(over, float(jnp.max(
-                diff - atol - rtol * jnp.abs(w.astype(jnp.float32)))))
-        custom = "tpu_custom_call" in text
-        print(f"[kernels] {name}: max |err| {err:.3g} (allowed {atol} + "
-              f"{rtol:.3g} * |ref|), tpu_custom_call={custom}", flush=True)
-        check(over <= 0, f"{name} matches kernels/ref.py")
-        check(custom, f"{name} compiled to a TPU custom call")
-
-    bf16_ulp = 2.0 ** -7
-    k = jax.random.split(jax.random.PRNGKey(0), 8)
-    # flash attention at tinyllama's head layout, 4096 tokens
-    q = jax.random.normal(k[0], (1, 32, 4096, 64), jnp.bfloat16)
-    kk = jax.random.normal(k[1], (1, 4, 4096, 64), jnp.bfloat16)
-    vv = jax.random.normal(k[2], (1, 4, 4096, 64), jnp.bfloat16)
-    report("flash_attention", ops.flash_attention, (q, kk, vv), {},
-           [ops.flash_attention(q, kk, vv)],
-           [jax.jit(ref.flash_attention_ref)(q, kk, vv)], 3e-2, bf16_ulp)
-    # rmsnorm over (32768, 2048) bf16 rows
-    x = jax.random.normal(k[3], (32768, 2048), jnp.bfloat16)
-    w = jax.random.normal(k[4], (2048,), jnp.float32)
-    report("rmsnorm", ops.rmsnorm, (x, w), {}, [ops.rmsnorm(x, w)],
-           [ref.rmsnorm_ref(x, w)], 5e-2, bf16_ulp)
-    # fused Adam and DGC over 2^24 f32 parameters
-    n = 1 << 24
-    p = jax.random.normal(k[5], (n,))
-    g = jax.random.normal(k[6], (n,))
-    m = jax.random.normal(k[7], (n,)) * 0.1
-    v = jnp.abs(jax.random.normal(k[0], (n,))) * 0.01
-    kw = dict(lr=3e-4, b1=0.9, b2=0.95, eps=1e-8, wd=0.1, c1=0.2, c2=0.1)
-    report("fused_adam", ops.fused_adam, (p, g, m, v), kw,
-           ops.fused_adam(p, g, m, v, **kw),
-           ref.fused_adam_ref(p, g, m, v, **kw), 1e-5)
-    want, _, thr = ref.dgc_topk_ref(g, 0.01)
-    report("dgc_mask", ops.dgc_mask, (g, thr), {},
-           [ops.dgc_mask(g, thr)[0]], [want], 0.0)
+    # flash attention at tinyllama's head layout, 4096 tokens, (B, S, H, D)
+    k = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(k[0], (1, 4096, 32, 64), jnp.bfloat16)
+    kk = jax.random.normal(k[1], (1, 4096, 4, 64), jnp.bfloat16)
+    vv = jax.random.normal(k[2], (1, 4096, 4, 64), jnp.bfloat16)
+    fn = jax.jit(ops.flash_attention)
+    text = fn.lower(q, kk, vv).compile().as_text()
+    got = fn(q, kk, vv).astype(jnp.float32)
+    bhsd = lambda x: x.transpose(0, 2, 1, 3)      # noqa: E731
+    want = bhsd(jax.jit(ref.flash_attention_ref)(*map(bhsd, (q, kk, vv)))
+                ).astype(jnp.float32)
+    # |got - want| <= atol + one bf16 ulp (2^-7) of |want|, elementwise
+    diff = jnp.abs(got - want)
+    err = float(jnp.max(diff))
+    over = float(jnp.max(diff - 3e-2 - 2.0 ** -7 * jnp.abs(want)))
+    custom = "tpu_custom_call" in text
+    print(f"[kernels] flash_attention: max |err| {err:.3g} (allowed 0.03 + "
+          f"2^-7 * |ref|), tpu_custom_call={custom}", flush=True)
+    check(over <= 0, "flash_attention matches kernels/ref.py")
+    check(custom, "flash_attention compiled to a TPU custom call")
 
 
 def four_chips(hw) -> None:

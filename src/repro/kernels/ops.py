@@ -1,141 +1,186 @@
-"""Jit'd public wrappers for the Pallas kernels: padding, reshaping, dtype
-management.  Each wrapper runs its kernel through the Pallas interpreter on
-the CPU backend and compiles it for the device on every other backend; the
-choice is made from ``jax.default_backend()`` when the wrapper is traced.
+"""Where, and how, the models' Pallas kernels run.
+
+This module makes the one choice of backend for the whole program
+(``on_tpu``), and for each kernel a model calls it gives
+
+  * ``<kernel>_reason(...)``: why the kernel cannot run here (the backend,
+    the kernel's own limits, or a mesh that splits the heads it holds
+    together), or ``None`` where it runs;
+  * the kernel entry itself, which takes the model's own layout, brings
+    it to the kernel's, and runs per shard under ``shard_map`` on a mesh
+    of more than one device.
+
+The models keep their XLA paths and ask for the reason first.  The kernels
+run through the Pallas interpreter on the CPU backend (``_interpret``) and
+compiled on every other, so a test can take the backend for a TPU
+(``on_tpu``) and still run them.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
+from repro.sharding import kernel_spec
 from . import causal_conv as _cc
 from . import flash_attention as _fa
 from . import ssd_chunk as _sc
-from . import fused_adam as _ad
-from . import rmsnorm as _rn
-from . import dgc_topk as _dg
+
+
+def on_tpu() -> bool:
+    """Whether the kernels' dispatch takes the TPU paths."""
+    return jax.default_backend() == "tpu"
 
 
 def _interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def _pad_to(x: jax.Array, axis: int, mult: int) -> Tuple[jax.Array, int]:
-    n = x.shape[axis]
-    target = -(-n // mult) * mult
-    if target == n:
-        return x, n
-    pad = [(0, 0)] * x.ndim
-    pad[axis] = (0, target - n)
-    return jnp.pad(x, pad), n
+def _per_shard(run, args, in_specs, out_specs):
+    """``run(*args)`` per shard under ``shard_map``, or as it is where
+    ``out_specs`` is ``None`` (no mesh of more than one device)."""
+    if out_specs is None:
+        return run(*args)
+    return jax.shard_map(run, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)(*args)
+
+
+def _rows(shape) -> Optional[PartitionSpec]:
+    """The batch split of a (B, S, E) operand on a mesh of more than one
+    device; ``None`` without one."""
+    spec = kernel_spec(shape, "batch", None, "heads")
+    return None if spec is None else PartitionSpec(spec[0])
+
+
+def _heads_split(shape) -> bool:
+    """Whether the mesh splits the heads (last) axis of a (B, S, E) x."""
+    spec = kernel_spec(shape, "batch", None, "heads")
+    return spec is not None and spec[2] is not None
 
 
 # ------------------------------------------------------------------ flash
+def _flash_specs(q, k):
+    """(q spec, k spec) of (B, S, H, hd) operands on a mesh of more than
+    one device; ``(None, None)`` without one."""
+    return tuple(kernel_spec(x.shape, "batch", None, "heads", None)
+                 for x in (q, k))
+
+
+def flash_attention_reason(q, k, v, *, window: Optional[int] = None,
+                           q_offset: int = 0) -> Optional[str]:
+    """Why ``flash_attention`` cannot take q (B, Sq, H, hd) and k, v
+    (B, Sk, KH, hd), or ``None`` where it runs."""
+    if not on_tpu():
+        return "backend"
+    if window is not None:
+        return "window"
+    if q_offset:
+        return "q_offset"
+    if q.shape[1] != k.shape[1]:
+        return "kv_length"
+    if not q.shape[3] == k.shape[3] == v.shape[3]:
+        return "head_dim"
+    if not _fa.backward_fits(q.shape[2] // k.shape[2], q.shape[1],
+                             q.shape[3]):
+        return "vmem"
+    qs, ks = _flash_specs(q, k)
+    if qs is not None and qs[2] != ks[2]:
+        return "heads_sharding"     # the GQA map would cross shards
+    return None
+
+
 @functools.partial(jax.jit, static_argnames=("causal",))
-def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool = True) -> jax.Array:
+def _flash(q: jax.Array, k: jax.Array, v: jax.Array, *,
+           causal: bool) -> jax.Array:
     """q: (B, H, S, D); k/v: (B, KH, S, D).  Pads S to the kernel's block
-    multiple (padded keys are masked); differentiable in q, k and v."""
+    multiple (padded keys are masked)."""
     S = q.shape[2]
     Sp = _fa.padded_len(S)
-    qp, kp, vp = (_pad_to(x, 2, Sp)[0] for x in (q, k, v))
-    out = _fa.flash_attention(qp, kp, vp, causal=causal, kv_len=S,
+    if Sp != S:
+        pad = ((0, 0), (0, 0), (0, Sp - S), (0, 0))
+        q, k, v = (jnp.pad(x, pad) for x in (q, k, v))
+    out = _fa.flash_attention(q, k, v, causal=causal, kv_len=S,
                               interpret=_interpret())
     return out[:, :, :S]
 
 
+def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                    causal: bool = True) -> jax.Array:
+    """The flash kernel on q (B, S, H, hd) and k, v (B, S, KH, hd) ->
+    (B, S, H, hd), differentiable in q, k and v."""
+    def run(q, k, v):
+        o = _flash(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                   v.transpose(0, 2, 1, 3), causal=causal)
+        return o.transpose(0, 2, 1, 3)
+
+    qs, ks = _flash_specs(q, k)
+    return _per_shard(run, (q, k, v), (qs, ks, ks), qs)
+
+
 # ------------------------------------------------------------ chunked ssd
+def ssd_reason(shape, heads: int, chunk: int) -> Optional[str]:
+    """Why ``ssd`` cannot take x of ``shape`` (B, S, E) in ``heads`` heads
+    at ``chunk``, or ``None`` where it runs."""
+    if not on_tpu():
+        return "backend"
+    if chunk not in _sc.CHUNKS:
+        return "chunk"
+    if _sc.head_block(heads, shape[2] // heads) is None:
+        return "width"
+    if _heads_split(shape):
+        return "heads_sharding"    # a head block would cross shards
+    return None
+
+
 @functools.partial(jax.jit, static_argnames=("chunk",))
-def ssd(x: jax.Array, b: jax.Array, c: jax.Array, dt: jax.Array,
-        cum: jax.Array, d: jax.Array, *, chunk: int):
-    """The chunked SSD (``ssd_chunk.ssd``): x (B, S, E), b/c (B, S, N),
-    dt/cum (B, H, S) f32, d (H,) -> y (B, S, E), final state (B, N, E)."""
+def _ssd(x, b, c, dt, cum, d, *, chunk: int):
     return _sc.ssd(x, b, c, dt, cum, d, chunk=chunk, interpret=_interpret())
 
 
+def ssd(D: jax.Array, X, Bm, Cm, dt, dA, chunk: int):
+    """The chunked SSD through the kernels of ``ssd_chunk.py``, in the
+    model's layout: X (B, S, H, P), Bm/Cm (B, S, N), dt and the log-decays
+    dA (B, S, H) f32, D (H,), S a multiple of ``chunk``.  Returns the final
+    f32 state (B, H, P, N) and y (B, S, H·P) in X's dtype."""
+    B_, S, H, P = X.shape
+    N = Bm.shape[-1]
+    dt, dA = dt.transpose(0, 2, 1), dA.transpose(0, 2, 1)   # (B,H,S)
+    cum = jnp.cumsum(dA.reshape(B_, H, S // chunk, chunk), axis=-1)
+    x = X.reshape(B_, S, H * P)
+    rows = _rows(x.shape)
+    y, state = _per_shard(functools.partial(_ssd, chunk=chunk),
+                          (x, Bm, Cm, dt, cum.reshape(B_, H, S), D),
+                          (rows,) * 5 + (PartitionSpec(),), rows)
+    return state.reshape(B_, N, H, P).transpose(0, 2, 3, 1), y
+
+
 # ------------------------------------------------------- causal conv silu
+def conv_silu_reason(shape) -> Optional[str]:
+    """Why ``conv_silu`` cannot run beside an SSD over x of ``shape``
+    (B, S, E), or ``None`` where it runs: it takes any length (its rows
+    are padded to 8) and width."""
+    if not on_tpu():
+        return "backend"
+    if _heads_split(shape):
+        return "heads_sharding"    # the kernel's rows hold every channel
+    return None
+
+
 @jax.jit
-def conv_silu(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
-    """SiLU(causal depthwise conv of x + b) (``causal_conv.conv_silu``): x
-    (B, S, C) with S a multiple of 8, w (K, C), b (C,)."""
+def _conv_silu(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
     return _cc.conv_silu(x, w, b, interpret=_interpret())
 
 
-# ------------------------------------------------------------- fused adam
-@functools.partial(jax.jit, static_argnames=("b1", "b2", "eps", "wd"))
-def fused_adam(p: jax.Array, g: jax.Array, m: jax.Array, v: jax.Array, *,
-               lr, b1: float, b2: float, eps: float, wd: float, c1, c2):
-    """Flat f32 vectors (N,) -> updated (p, m, v)."""
-    N = p.shape[0]
-    lane = _ad.LANE
-
-    def to2d(x):
-        xp, _ = _pad_to(x.astype(jnp.float32), 0, lane)
-        return xp.reshape(-1, lane)
-
-    p2, g2, m2, v2 = map(to2d, (p, g, m, v))
-    rows = p2.shape[0]
-    blk = min(_ad.BLOCK_ROWS, rows)
-    if rows % blk:
-        extra = blk - rows % blk
-        z = jnp.zeros((extra, lane), jnp.float32)
-        p2, g2, m2, v2 = (jnp.concatenate([a, z]) for a in (p2, g2, m2, v2))
-    po, mo, vo = _ad.fused_adam_2d(
-        p2, g2, m2, v2,
-        jnp.asarray(lr, jnp.float32).reshape(1),
-        jnp.asarray(c1, jnp.float32).reshape(1),
-        jnp.asarray(c2, jnp.float32).reshape(1),
-        b1=b1, b2=b2, eps=eps, wd=wd, interpret=_interpret())
-    return (po.reshape(-1)[:N], mo.reshape(-1)[:N], vo.reshape(-1)[:N])
-
-
-# ---------------------------------------------------------------- rmsnorm
-@jax.jit
-def rmsnorm(x: jax.Array, w: jax.Array, eps: float = 1e-6) -> jax.Array:
-    """x: (..., D), w: (D,) -> fused RMSNorm over the last dim."""
-    shape = x.shape
-    D = shape[-1]
-    x2 = x.reshape(-1, D)
-    x2p, _ = _pad_to(x2, 1, 128)
-    wp, _ = _pad_to(w, 0, 128)
-    rows = x2p.shape[0]
-    blk = min(_rn.BLOCK_ROWS, rows)
-    padr = 0
-    if rows % blk:
-        padr = blk - rows % blk
-        x2p = jnp.concatenate(
-            [x2p, jnp.zeros((padr, x2p.shape[1]), x2p.dtype)])
-    out = _rn.rmsnorm_2d(x2p, wp, eps=eps, d_real=D, interpret=_interpret())
-    if padr:
-        out = out[:-padr]
-    return out[:, :D].reshape(shape)
-
-
-# --------------------------------------------------------------- dgc mask
-@jax.jit
-def dgc_mask(g: jax.Array, threshold: jax.Array):
-    """Zero entries with |g| < threshold.  Returns (sparse g, kept count)."""
-    shape = g.shape
-    flat = g.reshape(-1).astype(jnp.float32)
-    N = flat.shape[0]
-    lane = _dg.LANE
-    fp, _ = _pad_to(flat, 0, lane)
-    g2 = fp.reshape(-1, lane)
-    rows = g2.shape[0]
-    blk = min(_dg.BLOCK_ROWS, rows)
-    padr = 0
-    if rows % blk:
-        padr = blk - rows % blk
-        g2 = jnp.concatenate([g2, jnp.zeros((padr, lane), jnp.float32)])
-    out, cnt = _dg.dgc_threshold_2d(
-        g2, jnp.asarray(threshold, jnp.float32).reshape(1),
-        interpret=_interpret())
-    if padr:
-        out, cnt = out[:-padr], cnt[:-padr]
-    sparse = out.reshape(-1)[:N].reshape(shape).astype(g.dtype)
-    # padded zeros never pass |0| >= thr for thr > 0
-    return sparse, jnp.sum(cnt)
+def conv_silu(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
+    """SiLU(causal depthwise conv of x + b) through ``causal_conv.py``: x
+    (B, S, C), w (K, C), b (C,) -> (B, S, C) in x's dtype."""
+    S = x.shape[1]
+    xp = jnp.pad(x, ((0, 0), (0, -S % 8), (0, 0)))
+    rows = _rows(xp.shape)
+    y = _per_shard(lambda x, wb: _conv_silu(x, *wb), (xp, (w, b)),
+                   (rows, PartitionSpec()), rows)
+    return y[:, :S]

@@ -4,7 +4,7 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 """§Perf hillclimb driver: run one cell with config overrides, tagged output.
 
     PYTHONPATH=src python -m repro.launch.hillclimb --arch tinyllama-1.1b \
-        --shape train_4k --tag iter2 --set layout=dp --set remat=dots
+        --shape train_4k --tag iter2 --set layout=dp
 
 With ``--search-whatif N`` the driver instead compiles the cell once and
 greedily hill-climbs the *optimization registry* (repro.core.optimize):

@@ -1,13 +1,15 @@
 """Attention blocks: GQA, MLA (DeepSeek-V2), local-window, and decode paths.
 
-Training/prefill attention goes through ``attend``: on a TPU, causal or full
-self-attention with one head dim runs the Pallas flash kernel
-(``repro/kernels/flash_attention.py``, forward and backward, score tiles in
-VMEM); everything else (the CPU, local windows, MLA's 192/128 heads,
-cross-attention, prefill continuation, and shapes whose backward would not
-fit VMEM) runs ``chunked_attention``, a ``lax.scan`` over KV blocks with
-streaming softmax (same math, same oracle).  Each dispatch writes one
-``attn.dispatch`` record to ``repro.obs`` as it is traced.
+Training/prefill attention goes through ``attend``, which asks
+``repro.kernels.ops`` whether the Pallas flash kernel can run
+(``ops.flash_attention_reason``): on a TPU, causal or full self-attention
+with one head dim whose backward fits VMEM runs it (``ops.flash_attention``,
+forward and backward, score tiles in VMEM, per shard under a mesh);
+everything else (the CPU, local windows, MLA's 192/128 heads,
+cross-attention, prefill continuation) runs ``chunked_attention``, a
+``lax.scan`` over KV blocks with streaming softmax (same math, same
+oracle).  Each dispatch writes one ``attn.dispatch`` record to
+``repro.obs`` as it is traced.
 
 Decode attention reads the KV cache (one new token per step).  MLA decode uses
 the *absorbed* formulation: queries are projected into the compressed KV space
@@ -24,8 +26,7 @@ import jax.numpy as jnp
 
 from repro import obs
 from repro.kernels import ops
-from repro.kernels.flash_attention import backward_fits
-from repro.sharding import kernel_spec, shard, use_weight
+from repro.sharding import shard, use_weight
 from .paramdecl import normal_param, zeros_param, ones_param, split_keys
 
 Params = Dict[str, Any]
@@ -117,57 +118,6 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.reshape(B, Sq, H, hd_v)
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _mesh_specs(q: jax.Array, k: jax.Array):
-    """(q spec, k spec) of the operands on the active mesh of more than one
-    device, which the kernel runs under in ``shard_map``; ``None``
-    without one."""
-    qs = kernel_spec(q.shape, "batch", None, "heads", None)
-    if qs is None:
-        return None
-    return qs, kernel_spec(k.shape, "batch", None, "heads", None)
-
-
-def _scan_reason(q, k, v, window, q_offset) -> Optional[str]:
-    """Why ``attend`` keeps the scan, or ``None`` where the kernel runs."""
-    if not _on_tpu():
-        return "backend"
-    if window is not None:
-        return "window"
-    if q_offset:
-        return "q_offset"
-    if q.shape[1] != k.shape[1]:
-        return "kv_length"
-    if not q.shape[3] == k.shape[3] == v.shape[3]:
-        return "head_dim"
-    if not backward_fits(q.shape[2] // k.shape[2], q.shape[1], q.shape[3]):
-        return "vmem"
-    specs = _mesh_specs(q, k)
-    if specs and specs[0][2] != specs[1][2]:
-        return "heads_sharding"     # the GQA map would cross shards
-    return None
-
-
-def _flash(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool
-           ) -> jax.Array:
-    """The Pallas kernel on (B, S, H, hd) operands, per shard under a mesh."""
-    def run(q, k, v):
-        o = ops.flash_attention(q.transpose(0, 2, 1, 3),
-                                k.transpose(0, 2, 1, 3),
-                                v.transpose(0, 2, 1, 3), causal=causal)
-        return o.transpose(0, 2, 1, 3)
-
-    specs = _mesh_specs(q, k)
-    if specs is None:
-        return run(q, k, v)
-    qs, ks = specs
-    return jax.shard_map(run, in_specs=(qs, ks, ks), out_specs=qs,
-                         check_vma=False)(q, k, v)
-
-
 def attend(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
            window: Optional[int] = None, chunk: int = 1024,
            q_offset: int = 0) -> jax.Array:
@@ -175,7 +125,8 @@ def attend(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
     the Pallas flash kernel where the backend and shapes allow it, else
     ``chunked_attention``.  The choice is made, and recorded as
     ``attn.dispatch``, when the caller is traced."""
-    reason = _scan_reason(q, k, v, window, q_offset)
+    reason = ops.flash_attention_reason(q, k, v, window=window,
+                                        q_offset=q_offset)
     attrs = {"path": "scan" if reason else "pallas",
              "q": list(q.shape), "k": list(k.shape), "v": list(v.shape),
              "causal": causal}
@@ -186,7 +137,7 @@ def attend(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
     if reason:
         return chunked_attention(q, k, v, causal=causal, window=window,
                                  chunk=chunk, q_offset=q_offset)
-    return _flash(q, k, v, causal)
+    return ops.flash_attention(q, k, v, causal=causal)
 
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,

@@ -85,7 +85,7 @@ class ModelConfig:
     serve_layout: str = "v2"      # decode/prefill layout (weight-stationary TP)
     serve_fsdp: bool = True       # False: replicate weights over data when
                                   # they fit (kills per-token FSDP gathers)
-    remat: str = "full"           # none | full | dots | offload
+    remat: str = "full"           # none | full
     scan_layers: bool = True
     attn_chunk: int = 1024
     loss_chunk: int = 2048

@@ -14,21 +14,23 @@ with a sequential inter-chunk state recurrence.  Two paths compute it, the
 same math in the same precisions:
 
   * on a TPU, where the chunk is one the kernels tile (128 or 256) and
-    the heads pair into 128 lanes, ``kernels/ssd_chunk.py``: Pallas kernels
-    with their own backward for each chunk's state, the recurrence over
-    chunks, and the diagonal block with the read-out and D·x over (batch,
-    chunk, head block), so no (Q,Q) tile leaves VMEM; the in-chunk
-    ``cumsum`` of the log-decays stays in XLA;
+    the heads pair into 128 lanes, ``ops.ssd`` (``kernels/ssd_chunk.py``):
+    Pallas kernels with their own backward for each chunk's state, the
+    recurrence over chunks, and the diagonal block with the read-out and
+    D·x over (batch, chunk, head block), so no (Q,Q) tile leaves VMEM; the
+    in-chunk ``cumsum`` of the log-decays stays in XLA;
   * elsewhere (the CPU, other chunks or widths, heads split across a mesh)
     a ``lax.scan`` of ``_ssd_chunk`` over the chunks, which is also the
     kernels' oracle in the tests.
 
 The xBC conv and its SiLU choose apart from the SSD, as they tile any
-length and width: on a TPU ``kernels/causal_conv.py``, one pass each way,
-elsewhere (and where heads are split across a mesh) the f32 shifted adds
-of ``_causal_conv``.  ``mamba2_forward`` chooses from what it is given and
-records both choices (``ssm.dispatch``: ``path`` and ``conv``, and for
-each path kept in XLA its reason).  Decode carries (conv window, SSM
+length and width: on a TPU ``ops.conv_silu`` (``kernels/causal_conv.py``),
+one pass each way, elsewhere (and where heads are split across a mesh) the
+f32 shifted adds of ``_causal_conv``.  ``repro.kernels.ops`` makes both
+choices (``ops.ssd_reason``, ``ops.conv_silu_reason``) and runs the
+kernels per batch shard under a mesh; ``mamba2_forward`` records them
+(``ssm.dispatch``: ``path`` and ``conv``, and for each path kept in XLA
+its reason).  Decode carries (conv window, SSM
 state) and is O(1) per token — which is why mamba2 runs the ``long_500k``
 cell that dense-attention archs skip.
 
@@ -50,17 +52,14 @@ Simplifications vs the reference CUDA implementation:
 
 from __future__ import annotations
 
-import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec
 
 from repro import obs
 from repro.kernels import ops
-from repro.kernels.ssd_chunk import CHUNKS, head_block
-from repro.sharding import kernel_spec, shard, use_weight
+from repro.sharding import shard, use_weight
 from .paramdecl import normal_param, zeros_param, ones_param, split_keys
 from .layers import rmsnorm_init, rmsnorm
 
@@ -95,15 +94,16 @@ def mamba2_init(key, d: int, d_state: int, dtype, *, expand: int = 2) -> Params:
 
 def _causal_conv(x: jax.Array, kernel: jax.Array, bias: jax.Array
                  ) -> jax.Array:
-    """Depthwise causal conv with bias via shifted adds.  x: (B,S,D);
-    kernel: (K,D), its last row on the current position; bias: (D,).
-    Sums in x's dtype (the caller passes f32, as the published kernel
-    accumulates)."""
-    out = x * kernel[-1] + bias
+    """SiLU of the depthwise causal conv with bias, via shifted adds, in
+    x's dtype.  x: (B,S,D); kernel: (K,D), its last row on the current
+    position; bias: (D,).  Sums in f32, as the published kernel
+    accumulates."""
+    xf, kernel = x.astype(F32), kernel.astype(F32)
+    out = xf * kernel[-1] + bias.astype(F32)
     for i in range(1, CONV_K):
-        shifted = jnp.pad(x, ((0, 0), (i, 0), (0, 0)))[:, :-i or None, :]
+        shifted = jnp.pad(xf, ((0, 0), (i, 0), (0, 0)))[:, :-i or None, :]
         out = out + shifted * kernel[CONV_K - 1 - i]
-    return out
+    return jax.nn.silu(out).astype(x.dtype)
 
 
 def _gated_norm(p: Params, y: jax.Array, z: jax.Array) -> jax.Array:
@@ -156,84 +156,6 @@ def _ssd_scan(D: jax.Array, X, Bm, Cm, dt, dA, chunk: int):
     return state_f, Yc.swapaxes(0, 1).reshape(B_, S, H * P)
 
 
-def _ssd_pallas(D: jax.Array, X, Bm, Cm, dt, dA, chunk: int):
-    """The chunked SSD through the Pallas kernels of
-    ``kernels/ssd_chunk.py``, from the in-chunk ``cumsum`` of dA.  Takes
-    and returns what ``_ssd_scan`` does."""
-    B_, S, H, P = X.shape
-    N = Bm.shape[-1]
-    dt, dA = dt.transpose(0, 2, 1), dA.transpose(0, 2, 1)   # (B,H,S)
-    cum = jnp.cumsum(dA.reshape(B_, H, S // chunk, chunk), axis=-1)
-    y, state = _per_shard(functools.partial(ops.ssd, chunk=chunk),
-                          X.reshape(B_, S, H * P), Bm, Cm, dt,
-                          cum.reshape(B_, H, S), D)
-    return state.reshape(B_, N, H, P).transpose(0, 2, 3, 1), y
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _x_spec(shape) -> Optional[PartitionSpec]:
-    """The spec of x (B, S, E) on a mesh of more than one device, under
-    which the kernels run per shard; ``None`` without one."""
-    return kernel_spec(shape, "batch", None, "heads")
-
-
-def _conv_reason(shape) -> Optional[str]:
-    """Why ``mamba2_forward`` keeps the XLA conv, or ``None`` where the
-    conv kernel runs; ``shape`` is x's (B, S, E).  The kernel takes any
-    length (its rows are padded to 8) and width."""
-    if not _on_tpu():
-        return "backend"
-    spec = _x_spec(shape)
-    if spec is not None and spec[2] is not None:
-        return "heads_sharding"    # the kernel's rows hold every channel
-    return None
-
-
-def _scan_reason(shape, H: int, chunk: int) -> Optional[str]:
-    """Why ``mamba2_forward`` keeps the scan, or ``None`` where the
-    kernels run; ``shape`` is x's (B, S, E)."""
-    if not _on_tpu():
-        return "backend"
-    if chunk not in CHUNKS:
-        return "chunk"
-    if head_block(H, HEAD_P) is None:
-        return "width"
-    spec = _x_spec(shape)
-    if spec is not None and spec[2] is not None:
-        return "heads_sharding"    # a head block would cross shards
-    return None
-
-
-def _per_shard(run, *args):
-    """``run`` on the operands as they are, or per batch shard under a mesh
-    of more than one device: every operand but the last is batch-leading,
-    the last is replicated."""
-    spec = _x_spec(args[0].shape)
-    if spec is None:
-        return run(*args)
-    rows = PartitionSpec(spec[0])
-    return jax.shard_map(run, in_specs=(rows,) * (len(args) - 1)
-                         + (PartitionSpec(),), out_specs=rows,
-                         check_vma=False)(*args)
-
-
-def _conv_silu(xbc_pre: jax.Array, w: jax.Array, b: jax.Array,
-               kernel: bool) -> jax.Array:
-    """SiLU of the causal xBC conv with bias, in xbc_pre's dtype: the
-    Pallas kernel (rows padded to a multiple of 8) or the f32 shifted adds
-    of ``_causal_conv``."""
-    if not kernel:
-        return jax.nn.silu(_causal_conv(
-            xbc_pre.astype(F32), w.astype(F32),
-            b.astype(F32))).astype(xbc_pre.dtype)
-    S = xbc_pre.shape[1]
-    x = jnp.pad(xbc_pre, ((0, 0), (0, -S % 8), (0, 0)))
-    return _per_shard(lambda x, wb: ops.conv_silu(x, *wb), x, (w, b))[:, :S]
-
-
 def mamba2_forward(p: Params, x: jax.Array, *, chunk: int = 128,
                    return_state: bool = False):
     """x: (B, S, d) -> (B, S, d) via the SSD chunked algorithm: the Pallas
@@ -250,8 +172,8 @@ def mamba2_forward(p: Params, x: jax.Array, *, chunk: int = 128,
         chunk = min(chunk, S)
         nc = (S + chunk - 1) // chunk
         pad = nc * chunk - S
-        reason = _scan_reason((B_, S, d_inner), H, chunk)
-        conv_reason = _conv_reason((B_, S, d_inner))
+        reason = ops.ssd_reason((B_, S, d_inner), H, chunk)
+        conv_reason = ops.conv_silu_reason((B_, S, d_inner))
         attrs = {"path": "scan" if reason else "pallas",
                  "conv": "xla" if conv_reason else "pallas",
                  "x": list(x.shape), "chunk": chunk, "chunks": nc,
@@ -268,8 +190,8 @@ def mamba2_forward(p: Params, x: jax.Array, *, chunk: int = 128,
             jnp.einsum("bsd,de->bse", x, use_weight(p["wx"], None, "heads")),
             jnp.einsum("bsd,dn->bsn", x, p["wB"]),
             jnp.einsum("bsd,dn->bsn", x, p["wC"])], axis=-1)
-        xbc = _conv_silu(xbc_pre, p["conv"], p["conv_b"],
-                         conv_reason is None)
+        conv = _causal_conv if conv_reason else ops.conv_silu
+        xbc = conv(xbc_pre, p["conv"], p["conv_b"])
         X = xbc[..., :d_inner].reshape(B_, S, H, HEAD_P)
         Bm, Cm = xbc[..., d_inner:d_inner + N], xbc[..., d_inner + N:]
         dt = jax.nn.softplus(
@@ -281,7 +203,7 @@ def mamba2_forward(p: Params, x: jax.Array, *, chunk: int = 128,
             X = jnp.pad(X, ((0, 0), (0, pad), (0, 0), (0, 0)))
             Bm, Cm, dt, dA = (jnp.pad(t, ((0, 0), (0, pad), (0, 0)))
                               for t in (Bm, Cm, dt, dA))
-        ssd = _ssd_scan if reason else _ssd_pallas
+        ssd = _ssd_scan if reason else ops.ssd
         state_f, Y = ssd(p["D"], X, Bm, Cm, dt, dA, chunk)
         Y = _gated_norm(p["norm"], Y[:, :S], z)
         out = jnp.einsum("bse,ed->bsd", Y,

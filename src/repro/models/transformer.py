@@ -451,16 +451,9 @@ def run_stack_prefill(cfg, stacked: Params, x: jax.Array, prefill_fn,
 def _remat_wrap(cfg, fn):
     if cfg.remat == "none":
         return fn
-    if cfg.remat == "dots":
-        return jax.checkpoint(
-            fn, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-    if cfg.remat == "offload":
-        return jax.checkpoint(
-            fn, policy=jax.checkpoint_policies.save_and_offload_only_these_names(
-                names_which_can_be_saved=[],
-                names_which_can_be_offloaded=["residual"],
-                offload_src="device", offload_dst="pinned_host"))
-    return jax.checkpoint(fn)      # "full": save nothing
+    if cfg.remat != "full":
+        raise ValueError(f"remat={cfg.remat!r}: 'none' or 'full'")
+    return jax.checkpoint(fn)      # save nothing
 
 
 def run_stack(cfg, stacked: Params, x: jax.Array, apply_fn,

@@ -4,18 +4,13 @@ Spec-mode aware: ``init`` over a SpecLeaf tree yields SpecLeaf moments with the
 same logical sharding, so the dry-run can lower ``train_step`` with the full
 (params, opt_state) structure and zero allocation.
 
-Two update paths:
-  * ``apply``       — standard per-leaf tree_map update (XLA fuses decently).
-  * ``apply_fused`` — flattens every leaf into one contiguous vector and runs a
-    single fused update (the FusedAdam of paper §6.3; the Pallas kernel in
-    ``repro/kernels/fused_adam.py`` is the TPU-tiled version of this op).
+``apply`` updates leaf by leaf (``tree_map``), which XLA fuses.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import functools
-from typing import Any, Callable, Dict, Optional, Tuple, Union
+from typing import Any, Callable, Dict, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -47,7 +42,6 @@ class AdamW:
     eps: float = 1e-8
     weight_decay: float = 0.1
     grad_clip: float = 1.0
-    fused: bool = False
 
     # ------------------------------------------------------------------ init
     def init(self, params) -> Dict[str, Any]:
@@ -68,8 +62,6 @@ class AdamW:
         return jnp.asarray(self.lr, jnp.float32)
 
     def apply(self, grads, state, params) -> Tuple[Any, Dict[str, Any]]:
-        if self.fused:
-            return self.apply_fused(grads, state, params)
         count = state["count"] + 1
         gnorm = global_norm(grads)
         scale = jnp.where(
@@ -96,46 +88,6 @@ class AdamW:
                             is_leaf=lambda x: isinstance(x, tuple))
         newv = jax.tree.map(lambda t: t[2], out,
                             is_leaf=lambda x: isinstance(x, tuple))
-        return newp, {"m": newm, "v": newv, "count": count, "gnorm": gnorm}
-
-    def apply_fused(self, grads, state, params) -> Tuple[Any, Dict[str, Any]]:
-        """Single fused update over one flattened vector (FusedAdam)."""
-        count = state["count"] + 1
-        leaves_p, treedef = jax.tree.flatten(params)
-        leaves_g = jax.tree.leaves(grads)
-        leaves_m = jax.tree.leaves(state["m"])
-        leaves_v = jax.tree.leaves(state["v"])
-        sizes = [l.size for l in leaves_p]
-        shapes = [l.shape for l in leaves_p]
-        dtypes = [l.dtype for l in leaves_p]
-        flat = lambda ls, dt: jnp.concatenate(
-            [l.reshape(-1).astype(dt) for l in ls])
-        p = flat(leaves_p, jnp.float32)
-        g = flat(leaves_g, jnp.float32)
-        m = flat(leaves_m, jnp.float32)
-        v = flat(leaves_v, jnp.float32)
-        gnorm = jnp.sqrt(jnp.sum(jnp.square(g)))
-        scale = jnp.where(gnorm > self.grad_clip,
-                          self.grad_clip / jnp.maximum(gnorm, 1e-12), 1.0) \
-            if self.grad_clip else jnp.ones((), jnp.float32)
-        g = g * scale
-        lr = self._lr(count)
-        c1 = 1.0 - self.b1 ** count.astype(jnp.float32)
-        c2 = 1.0 - self.b2 ** count.astype(jnp.float32)
-        from repro.kernels import ops as kops
-        p, m, v = kops.fused_adam(p, g, m, v, lr=lr, b1=self.b1, b2=self.b2,
-                                  eps=self.eps, wd=self.weight_decay,
-                                  c1=c1, c2=c2)
-        outs, ms, vs = [], [], []
-        off = 0
-        for size, shp, dt in zip(sizes, shapes, dtypes):
-            outs.append(p[off:off + size].reshape(shp).astype(dt))
-            ms.append(m[off:off + size].reshape(shp))
-            vs.append(v[off:off + size].reshape(shp))
-            off += size
-        newp = jax.tree.unflatten(treedef, outs)
-        newm = jax.tree.unflatten(treedef, ms)
-        newv = jax.tree.unflatten(treedef, vs)
         return newp, {"m": newm, "v": newv, "count": count, "gnorm": gnorm}
 
     @staticmethod

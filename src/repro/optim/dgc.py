@@ -4,8 +4,7 @@ Top-k gradient sparsification with local error feedback: each step transmits
 only the largest-magnitude ``ratio`` fraction of gradient entries; the residual
 accumulates locally and is added back next step.  The Daydream what-if
 (``core/whatif.py::what_if_dgc``) predicts its efficacy; this module is the
-runnable implementation the prediction can be validated against, and the
-Pallas ``dgc_topk`` kernel is its TPU-tiled selection stage.
+runnable implementation the prediction can be validated against.
 """
 
 from __future__ import annotations

@@ -1,4 +1,5 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (interpret mode)."""
+"""The flash attention kernel vs its pure-jnp oracle and the XLA scan, and
+the choice ``attend`` makes through ``kernels/ops`` (interpret mode)."""
 
 import json
 
@@ -29,6 +30,13 @@ def _bshd(x):
     return x.transpose(0, 2, 1, 3)
 
 
+def _flash_bhsd(causal):
+    """``ops.flash_attention`` on (B, H, S, D) operands, the oracle's
+    layout."""
+    return lambda q, k, v: _bshd(ops.flash_attention(  # noqa: E731
+        _bshd(q), _bshd(k), _bshd(v), causal=causal))
+
+
 @pytest.mark.parametrize("B,H,KH,S,D", [
     (1, 2, 1, 128, 64),
     (2, 4, 2, 256, 128),
@@ -44,9 +52,7 @@ def test_flash_attention_sweep(B, H, KH, S, D, dtype, causal):
     """Output, and dq, dk, dv of the kernel's custom VJP against autodiff
     of the f32 full-score oracle."""
     q, k, v, do = _qkv(B, H, KH, S, D, dtype)
-    out, dq, dk, dv = _vjp(
-        lambda q, k, v: ops.flash_attention(q, k, v, causal=causal),
-        q, k, v, do)
+    out, dq, dk, dv = _vjp(_flash_bhsd(causal), q, k, v, do)
     want = ref.flash_attention_ref(q, k, v, causal=causal)
     atol = 2e-3 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -65,12 +71,12 @@ def test_flash_attention_sweep(B, H, KH, S, D, dtype, causal):
 def test_flash_attention_grad_matches_chunked_scan(S):
     """The kernel and the XLA scan it replaces agree in f32, forward and
     backward (the scan at chunks that do not divide S)."""
-    q, k, v, do = _qkv(1, 14, 2, S, 64, jnp.float32, seed=1)
-    got = _vjp(lambda q, k, v: ops.flash_attention(q, k, v), q, k, v, do)
+    q, k, v, do = map(_bshd, _qkv(1, 14, 2, S, 64, jnp.float32, seed=1))
+    got = _vjp(ops.flash_attention, q, k, v, do)
     want = _vjp(lambda q, k, v: attention.chunked_attention(
-        q, k, v, chunk=384), *map(_bshd, (q, k, v, do)))
+        q, k, v, chunk=384), q, k, v, do)
     for g, w in zip(got, want):
-        np.testing.assert_allclose(g, _bshd(w), atol=2e-5, rtol=1e-4)
+        np.testing.assert_allclose(g, w, atol=2e-5, rtol=1e-4)
 
 
 @pytest.fixture
@@ -101,11 +107,10 @@ def _attend_shapes(Sq=64, Sk=64, hd=64, hd_v=64):
     ("head_dim", {}, {"hd": 96, "hd_v": 64}),
     ("vmem", {}, {"Sq": 40960, "Sk": 40960}),
 ])
-def test_attend_keeps_the_scan(monkeypatch, dispatch_records, reason, kwargs,
+def test_attend_keeps_the_scan(on_tpu, dispatch_records, reason, kwargs,
                                shapes):
     """On a TPU backend, each shape or option the kernel does not take
     sends ``attend`` to the scan, once per trace, and says why."""
-    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     fn = lambda q, k, v: attention.attend(q, k, v, **kwargs)  # noqa: E731
     jax.eval_shape(fn, *_attend_shapes(**shapes))
     [rec] = dispatch_records()
@@ -119,28 +124,26 @@ def test_attend_keeps_the_scan_off_tpu(dispatch_records):
                    "k": [2, 64, 2, 64], "v": [2, 64, 2, 64], "causal": True}
 
 
-def test_attend_keeps_the_scan_where_heads_split_apart(monkeypatch,
+def test_attend_keeps_the_scan_where_heads_split_apart(on_tpu,
                                                        dispatch_records):
     """On a model axis of 2, 14 q heads split but 7 kv heads do not: the
     GQA map would cross shards, so the scan runs.  With 2 kv heads both
     split and the kernel runs."""
     from jax.sharding import AbstractMesh
-    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     f32 = jnp.float32
     q = jax.ShapeDtypeStruct((2, 64, 14, 64), f32)
     k = jax.ShapeDtypeStruct((2, 64, 7, 64), f32)
     mesh = AbstractMesh((1, 2), ("data", "model"))
     with jax.sharding.use_abstract_mesh(mesh):
-        assert attention._scan_reason(q, k, k, None, 0) == "heads_sharding"
+        assert ops.flash_attention_reason(q, k, k) == "heads_sharding"
         k2 = jax.ShapeDtypeStruct((2, 64, 2, 64), f32)
-        assert attention._scan_reason(q, k2, k2, None, 0) is None
+        assert ops.flash_attention_reason(q, k2, k2) is None
 
 
-def test_attend_runs_the_kernel_on_tpu(monkeypatch, dispatch_records):
+def test_attend_runs_the_kernel_on_tpu(on_tpu, dispatch_records):
     """With the backend taken for a TPU (the kernel itself interpreted),
     causal self-attention runs the kernel and agrees with the scan,
     gradients included."""
-    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     q, k, v, do = (_bshd(x) for x in _qkv(2, 14, 2, 160, 64, jnp.float32,
                                            seed=2))
     got = _vjp(attention.attend, q, k, v, do)
@@ -151,58 +154,3 @@ def test_attend_runs_the_kernel_on_tpu(monkeypatch, dispatch_records):
                 q, k, v, do)
     for g, w in zip(got, want):
         np.testing.assert_allclose(g, w, atol=2e-5, rtol=1e-4)
-
-
-@pytest.mark.parametrize("n", [100, 1024, 5000, 1 << 14])
-def test_fused_adam_sweep(n):
-    key = jax.random.PRNGKey(1)
-    p = jax.random.normal(key, (n,))
-    g = jax.random.normal(jax.random.fold_in(key, 1), (n,))
-    m = jax.random.normal(jax.random.fold_in(key, 2), (n,)) * 0.1
-    v = jnp.abs(jax.random.normal(jax.random.fold_in(key, 3), (n,))) * 0.01
-    kw = dict(lr=3e-4, b1=0.9, b2=0.95, eps=1e-8, wd=0.1, c1=0.2, c2=0.1)
-    po, mo, vo = ops.fused_adam(p, g, m, v, **kw)
-    pr, mr, vr = ref.fused_adam_ref(p, g, m, v, **kw)
-    np.testing.assert_allclose(po, pr, atol=1e-5)
-    np.testing.assert_allclose(mo, mr, atol=1e-6)
-    np.testing.assert_allclose(vo, vr, atol=1e-6)
-
-
-@pytest.mark.parametrize("shape", [(4, 64), (3, 5, 300), (16, 1024), (1, 7)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_rmsnorm_sweep(shape, dtype):
-    key = jax.random.PRNGKey(2)
-    x = jax.random.normal(key, shape, dtype)
-    w = jax.random.normal(jax.random.fold_in(key, 1), (shape[-1],),
-                          jnp.float32)
-    out = ops.rmsnorm(x, w)
-    want = ref.rmsnorm_ref(x, w)
-    atol = 1e-5 if dtype == jnp.float32 else 5e-2
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(want, np.float32), atol=atol)
-
-
-@pytest.mark.parametrize("shape,ratio", [((100,), 0.1), ((123, 45), 0.01),
-                                         ((4096,), 0.001)])
-def test_dgc_threshold_matches_topk(shape, ratio):
-    g = jax.random.normal(jax.random.PRNGKey(3), shape)
-    want, k, thr = ref.dgc_topk_ref(g, ratio)
-    got, cnt = ops.dgc_mask(g, thr)
-    np.testing.assert_allclose(got, want, atol=0)
-    assert int(cnt) >= k            # ties may keep extras
-
-
-def test_fused_adam_multi_step_agrees_with_optimizer():
-    """AdamW(fused=True) == AdamW(fused=False) over several steps."""
-    from repro.optim import AdamW
-    params = {"a": jnp.ones((130,)) * 0.3,
-              "b": {"w": jnp.linspace(-1, 1, 77)}}
-    grads = jax.tree.map(lambda p: p * 0.1 + 0.01, params)
-    o1, o2 = AdamW(lr=1e-2), AdamW(lr=1e-2, fused=True)
-    s1, s2 = o1.init(params), o2.init(params)
-    p1 = p2 = params
-    for _ in range(3):
-        p1, s1 = o1.apply(grads, s1, p1)
-        p2, s2 = o2.apply(grads, s2, p2)
-    for a, b in zip(jax.tree.leaves(p1), jax.tree.leaves(p2)):
-        np.testing.assert_allclose(a, b, atol=1e-5)

@@ -144,3 +144,22 @@ def test_grad_accum_equivalence():
           "step": jnp.zeros((), jnp.int32)}
     _, m2 = jax.jit(make_train_step(cfg2, opt))(s2, batch)
     assert float(m1["loss"]) == pytest.approx(float(m2["loss"]), rel=1e-4)
+
+
+@pytest.mark.parametrize("shape", [(4, 64), (3, 5, 300), (16, 1024), (1, 7)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_rmsnorm_sweep(shape, dtype):
+    """``layers.rmsnorm`` against the float64 oracle, in f32 and bf16 (the
+    output rounded once to bf16: half an ulp, 2^-8 of the value)."""
+    from repro.models.layers import rmsnorm
+    rng = np.random.default_rng(2)
+    x = jnp.asarray(rng.standard_normal(shape), dtype)
+    w = rng.standard_normal(shape[-1]).astype(np.float32)
+    out = rmsnorm({"scale": jnp.asarray(w)}, x)
+    assert out.dtype == dtype
+    xf = np.asarray(x.astype(jnp.float32), np.float64)
+    want = xf / np.sqrt(np.mean(xf * xf, -1, keepdims=True) + 1e-6) * w
+    rtol = 1e-5 if dtype == jnp.float32 else 2.0 ** -8
+    np.testing.assert_allclose(np.asarray(out.astype(jnp.float32),
+                                          np.float64), want, rtol=rtol,
+                               atol=1e-6)

@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 from repro import obs
-from repro.models import ssm
+from repro.kernels import ops
 from repro.models.ssm import (CONV_K, HEAD_P, mamba2_cache_spec,
                               mamba2_decode, mamba2_forward, mamba2_init)
 
@@ -178,13 +178,6 @@ def test_decode_after_prefill_matches_forward(S, chunk):
 
 
 # ------------------------------------------------ the Pallas kernel path
-@pytest.fixture
-def on_tpu(monkeypatch):
-    """``mamba2_forward`` dispatches as on a TPU; the kernel itself runs in
-    interpret mode on the CPU."""
-    monkeypatch.setattr(ssm, "_on_tpu", lambda: True)
-
-
 def _out_and_grads(fn, p, x, r):
     """fn(p, x), and the gradient of sum(fn · r) in every parameter (x, B
     and C through their projections, dt through dt_bias and w_dt, A_log,
@@ -211,16 +204,17 @@ KERNEL_CASES = {"pad": (2, 196, 128, D_MODEL),
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("case", list(KERNEL_CASES))
-def test_kernel_matches_scan_and_recurrence(monkeypatch, case, dtype):
+def test_kernel_matches_scan_and_recurrence(on_tpu, case, dtype):
     """The kernel path's output and every gradient equal the scan's, and
     in f32 the sequential recurrence's."""
     B, S, chunk, d = KERNEL_CASES[case]
     p = jax.tree.map(lambda a: a.astype(dtype) if a.ndim > 1 else a,
                      _params(d_model=d))
     x, r = _x(S, B, d_model=d).astype(dtype), _x(S, B, seed=2, d_model=d)
+    on_tpu(False)
     fwd = jax.jit(lambda p, x: mamba2_forward(p, x, chunk=chunk))
     want = _out_and_grads(fwd, p, x, r)
-    monkeypatch.setattr(ssm, "_on_tpu", lambda: True)
+    on_tpu(True)
     fwd = jax.jit(lambda p, x: mamba2_forward(p, x, chunk=chunk))
     got = _out_and_grads(fwd, p, x, r)
     # the two paths sum in different orders; A_log's gradient, a sum over
@@ -251,14 +245,15 @@ def test_kernel_gradients_finite_where_decay_sums_overflow(on_tpu):
 
 
 @pytest.mark.parametrize("S", [200, 512])
-def test_kernel_prefill_state_matches_scan(monkeypatch, S):
+def test_kernel_prefill_state_matches_scan(on_tpu, S):
     """``return_state`` on the kernel path gives the scan's final f32 state
     and conv tail (S 200: the padded tail leaves the state as it was)."""
     p, x = _params(), _x(S)
+    on_tpu(False)
     fwd = jax.jit(lambda p, x: mamba2_forward(p, x, chunk=128,
                                               return_state=True))
     out, cache = fwd(p, x)
-    monkeypatch.setattr(ssm, "_on_tpu", lambda: True)
+    on_tpu(True)
     fwd = jax.jit(lambda p, x: mamba2_forward(p, x, chunk=128,
                                               return_state=True))
     k_out, k_cache = fwd(p, x)
@@ -270,7 +265,7 @@ def test_kernel_prefill_state_matches_scan(monkeypatch, S):
     _close(k_cache["state"], _reference(p, x)[1], 1e-5)
 
 
-def test_kernel_gradient_through_the_state_matches_scan(monkeypatch):
+def test_kernel_gradient_through_the_state_matches_scan(on_tpu):
     """The final state's cotangent runs the kernels' recurrence backwards
     from a non-zero start: its gradients equal the scan's."""
     p, x = _params(), _x(256, B=1)
@@ -283,8 +278,9 @@ def test_kernel_gradient_through_the_state_matches_scan(monkeypatch):
             out, cache = mamba2_forward(p, x, chunk=128, return_state=True)
             return jnp.sum(out * r) + jnp.sum(cache["state"] * rs)
         return jax.jit(jax.grad(loss, argnums=(0, 1)))(p, x)
+    on_tpu(False)
     want = grads()
-    monkeypatch.setattr(ssm, "_on_tpu", lambda: True)
+    on_tpu(True)
     got = grads()
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         _close(a, b, 1e-4)
@@ -299,16 +295,16 @@ def test_dispatch_records_the_kernel(on_tpu, ssm_records):
          "return_state": False}]
 
 
-def test_conv_kernel_runs_where_the_ssd_keeps_the_scan(monkeypatch,
-                                                       ssm_records):
+def test_conv_kernel_runs_where_the_ssd_keeps_the_scan(on_tpu, ssm_records):
     """At chunk 64, which the SSD kernels do not tile, the scan runs after
     the conv kernel, and the block's output and gradients equal the CPU
     path's."""
     p, x = _params(), _x(196)
     r = _x(196, seed=2)
+    on_tpu(False)
     fwd = jax.jit(lambda p, x: mamba2_forward(p, x, chunk=64))
     want = _out_and_grads(fwd, p, x, r)
-    monkeypatch.setattr(ssm, "_on_tpu", lambda: True)
+    on_tpu(True)
     fwd = jax.jit(lambda p, x: mamba2_forward(p, x, chunk=64))
     got = _out_and_grads(fwd, p, x, r)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
@@ -319,7 +315,7 @@ def test_conv_kernel_runs_where_the_ssd_keeps_the_scan(monkeypatch,
 
 def test_dispatch_picks_the_kernel_at_the_cells_shape(on_tpu):
     """2 × 4096 positions of 80 heads of 64 at chunk 256 (mamba2-2.7b)."""
-    assert ssm._scan_reason((2, 4096, 80 * HEAD_P), 80, 256) is None
+    assert ops.ssd_reason((2, 4096, 80 * HEAD_P), 80, 256) is None
 
 
 @pytest.mark.parametrize("reason,H,chunk", [
@@ -327,7 +323,7 @@ def test_dispatch_picks_the_kernel_at_the_cells_shape(on_tpu):
     ("width", 5, 256)])
 def test_dispatch_names_what_the_kernel_cannot_tile(on_tpu, reason, H,
                                                     chunk):
-    assert ssm._scan_reason((2, 4096, H * HEAD_P), H, chunk) == reason
+    assert ops.ssd_reason((2, 4096, H * HEAD_P), H, chunk) == reason
 
 
 def test_dispatch_keeps_the_scan_where_heads_split_apart(on_tpu):
@@ -338,12 +334,12 @@ def test_dispatch_keeps_the_scan_where_heads_split_apart(on_tpu):
     shape = (2, 4096, 80 * HEAD_P)
     with jax.sharding.use_abstract_mesh(AbstractMesh((1, 2),
                                                      ("data", "model"))):
-        assert ssm._scan_reason(shape, 80, 256) == "heads_sharding"
-        assert ssm._conv_reason(shape) == "heads_sharding"
+        assert ops.ssd_reason(shape, 80, 256) == "heads_sharding"
+        assert ops.conv_silu_reason(shape) == "heads_sharding"
     with jax.sharding.use_abstract_mesh(AbstractMesh((2, 1),
                                                      ("data", "model"))):
-        assert ssm._scan_reason(shape, 80, 256) is None
-        assert ssm._conv_reason(shape) is None
+        assert ops.ssd_reason(shape, 80, 256) is None
+        assert ops.conv_silu_reason(shape) is None
 
 
 @pytest.mark.parametrize("H,chunk", [(80, 64), (80, 200), (5, 256)])
@@ -351,5 +347,5 @@ def test_conv_dispatch_apart_from_the_ssds(on_tpu, H, chunk):
     """A chunk or a head count the SSD kernels cannot tile leaves the conv
     kernel on."""
     shape = (2, 4096, H * HEAD_P)
-    assert ssm._scan_reason(shape, H, chunk) is not None
-    assert ssm._conv_reason(shape) is None
+    assert ops.ssd_reason(shape, H, chunk) is not None
+    assert ops.conv_silu_reason(shape) is None

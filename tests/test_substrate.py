@@ -53,6 +53,20 @@ class TestData:
 
 
 # ------------------------------------------------------------------ optim
+def _adamw_ref(opt, p, g, m, v, *, count):
+    """One AdamW step in float64 from a state at ``count``: (p, m, v, the
+    gradient's norm before clipping)."""
+    norm = np.sqrt(np.sum(g * g))
+    if opt.grad_clip and norm > opt.grad_clip:
+        g = g * (opt.grad_clip / norm)
+    t = count + 1
+    m = opt.b1 * m + (1 - opt.b1) * g
+    v = opt.b2 * v + (1 - opt.b2) * g * g
+    step = (m / (1 - opt.b1 ** t)) / (np.sqrt(v / (1 - opt.b2 ** t))
+                                      + opt.eps) + opt.weight_decay * p
+    return p - opt.lr * step, m, v, norm
+
+
 class TestOptim:
     def test_adamw_decreases_quadratic(self):
         opt = AdamW(lr=0.1, weight_decay=0.0)
@@ -76,6 +90,73 @@ class TestOptim:
         assert float(f(jnp.asarray(0))) == pytest.approx(0.0)
         assert float(f(jnp.asarray(10))) == pytest.approx(1.0, rel=0.2)
         assert float(f(jnp.asarray(100))) < 0.01
+
+    @pytest.mark.parametrize("n", [100, 1024, 5000, 1 << 14])
+    def test_adamw_sweep(self, n):
+        """One ``apply`` from a state at count 4 (bias correction at step
+        5), the gradient clipped to norm 1 and decoupled weight decay,
+        against the float64 oracle."""
+        rng = np.random.default_rng(n)
+        p, g = rng.standard_normal(n), rng.standard_normal(n)
+        m = rng.standard_normal(n) * 0.1
+        v = np.abs(rng.standard_normal(n)) * 0.01
+        opt = AdamW(lr=3e-4, b1=0.9, b2=0.95, eps=1e-8, weight_decay=0.1,
+                    grad_clip=1.0)
+        f32 = lambda a: {"w": jnp.asarray(a, jnp.float32)}   # noqa: E731
+        state = {"m": f32(m), "v": f32(v), "count": jnp.asarray(4, jnp.int32),
+                 "gnorm": jnp.zeros((), jnp.float32)}
+        newp, state = opt.apply(f32(g), state, f32(p))
+        want = _adamw_ref(opt, *(np.asarray(f32(a)["w"], np.float64)
+                                 for a in (p, g, m, v)), count=4)
+        assert int(state["count"]) == 5
+        np.testing.assert_allclose(state["gnorm"], want[3], rtol=1e-6)
+        for got, w in zip((newp["w"], state["m"]["w"], state["v"]["w"]),
+                          want[:3]):
+            np.testing.assert_allclose(np.asarray(got, np.float64), w,
+                                       rtol=1e-5, atol=1e-7)
+
+    def test_adamw_multi_step_matches_oracle(self):
+        """Three ``apply`` steps over a two-leaf tree, from a fresh state,
+        against the float64 oracle run as many times."""
+        params = {"a": jnp.ones((130,)) * 0.3,
+                  "b": {"w": jnp.linspace(-1, 1, 77)}}
+        opt = AdamW(lr=1e-2)
+        state = opt.init(params)
+        flat = lambda t: np.concatenate(   # noqa: E731
+            [np.asarray(l, np.float64) for l in jax.tree.leaves(t)])
+        p_ref = flat(params)
+        m_ref = v_ref = np.zeros_like(p_ref)
+        p = params
+        for count in range(3):
+            grads = jax.tree.map(lambda a: a * 0.1 + 0.01, p)
+            g_ref = flat(grads)
+            p, state = opt.apply(grads, state, p)
+            p_ref, m_ref, v_ref, _ = _adamw_ref(opt, p_ref, g_ref, m_ref,
+                                                v_ref, count=count)
+            np.testing.assert_allclose(flat(p), p_ref, rtol=1e-5, atol=1e-7)
+        np.testing.assert_allclose(flat(state["m"]), m_ref, rtol=1e-5,
+                                   atol=1e-7)
+        np.testing.assert_allclose(flat(state["v"]), v_ref, rtol=1e-5,
+                                   atol=1e-9)
+
+    @pytest.mark.parametrize("shape,ratio", [((100,), 0.1),
+                                             ((123, 45), 0.01),
+                                             ((4096,), 0.001)])
+    def test_dgc_keeps_exact_top_k(self, shape, ratio):
+        """``dgc_compress`` then ``dgc_decompress`` give the gradient with
+        all but its k = round(ratio · size) largest magnitudes zeroed."""
+        from repro.optim.dgc import dgc_compress, dgc_decompress
+        g = np.random.default_rng(3).standard_normal(shape).astype(np.float32)
+        vals, idx = dgc_compress(jnp.asarray(g), ratio)
+        got = dgc_decompress(vals, idx, shape)
+        flat = g.reshape(-1).astype(np.float64)
+        k = max(1, int(round(ratio * flat.size)))
+        keep = np.argsort(-np.abs(flat), kind="stable")[:k]
+        want = np.zeros_like(flat)
+        want[keep] = flat[keep]
+        assert vals.shape == (k,)
+        np.testing.assert_array_equal(np.asarray(got, np.float64),
+                                      want.reshape(shape))
 
     def test_dgc_error_feedback_conserves(self):
         g = {"w": jax.random.normal(jax.random.PRNGKey(0), (1000,))}

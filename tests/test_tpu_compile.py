@@ -19,8 +19,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.core import extract_graph, parse_hlo_module, aggregate_costs
 from repro.core.task import DEVICE_STREAM
-from repro.kernels import (causal_conv, dgc_topk, flash_attention, fused_adam,
-                           rmsnorm, ssd_chunk)
+from repro.kernels import causal_conv, flash_attention, ops, ssd_chunk
 from repro.models.model import count_params
 from repro.models import attention
 from repro.train import Trainer, TrainerConfig
@@ -60,10 +59,8 @@ def _kernel_case(name, chip):
     128), the backward at the ``internvl2-1b.train`` cell's widths (3 x 4096,
     14 q / 2 kv heads of 64), the chunked SSD and the xBC conv at the
     ``mamba2-2.7b.train`` cell's (2 x 4096, 80 heads of 64, state 128,
-    chunk 256, 5376 conv channels), rmsnorm over 32768 rows of 2048, fused
-    Adam and DGC over 2^24 parameters."""
+    chunk 256, 5376 conv channels)."""
     f32, bf16 = jnp.float32, jnp.bfloat16
-    rows = (1 << 24) // fused_adam.LANE
     if name == "flash_attention":
         return (lambda q, k, v: flash_attention.flash_attention(
                     q, k, v, interpret=False),
@@ -91,29 +88,17 @@ def _kernel_case(name, chip):
                      _sds((2, 80, 4096), f32, chip),
                      _sds((2, 80, 4096), f32, chip),
                      _sds((80,), f32, chip)])
-    if name == "causal_conv_bwd":
-        def conv(x, w, b):
-            y = causal_conv.conv_silu(x, w, b, interpret=False)
-            return jnp.sum(y.astype(f32) ** 2)
-        return (jax.grad(conv, argnums=(0, 1, 2)),
-                [_sds((2, 4096, 5376), bf16, chip),
-                 _sds((4, 5376), bf16, chip), _sds((5376,), bf16, chip)])
-    if name == "rmsnorm":
-        return (lambda x, w: rmsnorm.rmsnorm_2d(x, w, interpret=False),
-                [_sds((32768, 2048), bf16, chip), _sds((2048,), bf16, chip)])
-    if name == "fused_adam":
-        vec = _sds((rows, fused_adam.LANE), f32, chip)
-        one = _sds((1,), f32, chip)
-        return (lambda *a: fused_adam.fused_adam_2d(*a, interpret=False),
-                [vec, vec, vec, vec, one, one, one])
-    return (lambda g, t: dgc_topk.dgc_threshold_2d(g, t, interpret=False),
-            [_sds((rows, dgc_topk.LANE), f32, chip), _sds((1,), f32, chip)])
+    def conv(x, w, b):
+        y = causal_conv.conv_silu(x, w, b, interpret=False)
+        return jnp.sum(y.astype(f32) ** 2)
+    return (jax.grad(conv, argnums=(0, 1, 2)),
+            [_sds((2, 4096, 5376), bf16, chip),
+             _sds((4, 5376), bf16, chip), _sds((5376,), bf16, chip)])
 
 
 @pytest.mark.parametrize("name", ["flash_attention", "flash_attention_bwd",
                                   "ssd_chunk", "ssd_chunk_bwd",
-                                  "causal_conv_bwd", "rmsnorm", "fused_adam",
-                                  "dgc_topk"])
+                                  "causal_conv_bwd"])
 def test_kernel_compiles_to_tpu_custom_call(one_chip, name):
     fn, args = _kernel_case(name, one_chip)
     text = jax.jit(fn).lower(*args).compile().as_text()
@@ -165,15 +150,12 @@ def test_training_attention_on_four_chips_gathers_no_activation(topo,
 
 
 def test_chunked_ssd_on_four_chips_gathers_no_activation(topo, monkeypatch):
-    """On a (data=4, model=1) mesh, the SSD and xBC-conv kernels run per
-    batch shard inside ``shard_map``: the SSD's three forward and three
-    backward calls and the conv's two compile to TPU kernels and no
-    all-gather of an operand or a gradient appears."""
-    import functools
+    """On a (data=4, model=1) mesh, ``ops.conv_silu`` and ``ops.ssd`` run
+    their kernels per batch shard inside ``shard_map``: the SSD's three
+    forward and three backward calls and the conv's two compile to TPU
+    kernels and no all-gather of an operand or a gradient appears."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     import numpy as np
-    from repro.kernels import ops
-    from repro.models import ssm
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
     rows = NamedSharding(mesh, P("data"))
@@ -181,15 +163,14 @@ def test_chunked_ssd_on_four_chips_gathers_no_activation(topo, monkeypatch):
     bf16, f32 = jnp.bfloat16, jnp.float32
     args = [_sds((8, 1024, 16 * 64), bf16, rows),
             _sds((8, 1024, 128), bf16, rows), _sds((8, 1024, 128), bf16, rows),
-            _sds((8, 16, 1024), f32, rows), _sds((8, 16, 1024), f32, rows),
+            _sds((8, 1024, 16), f32, rows), _sds((8, 1024, 16), f32, rows),
             _sds((16,), f32, whole), _sds((4, 16 * 64), bf16, whole),
             _sds((16 * 64,), bf16, whole)]
 
-    def loss(x, b, c, dt, cum, d, w, wb):
+    def loss(x, b, c, dt, da, d, w, wb):
         with jax.named_scope("ssm"):
-            x = ssm._conv_silu(x, w, wb, True)
-            y, state = ssm._per_shard(functools.partial(ops.ssd, chunk=256),
-                                      x, b, c, dt, cum, d)
+            x = ops.conv_silu(x, w, wb).reshape(8, 1024, 16, 64)
+            state, y = ops.ssd(d, x, b, c, dt, da, 256)
         return jnp.sum(y.astype(f32) ** 2) + jnp.sum(state)
     with jax.set_mesh(mesh):
         text = jax.jit(jax.grad(loss, argnums=tuple(range(8)))).lower(
