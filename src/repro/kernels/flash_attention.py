@@ -21,6 +21,10 @@ Layouts: q (B, H, S, D), k/v (B, KH, S, D); GQA maps q head h to kv head
 h // (H // KH) in the index maps, so K/V are never repeated in HBM.  S must
 be ``padded_len(S)`` (the ops wrapper pads; ``kv_len`` is the number of
 real keys).  D may be any width the block holds whole.
+
+The forward names the output and ``lse`` it keeps for the backward
+(``SAVED``, with ``checkpoint_name``), so a remat policy that saves those
+names keeps them and the backward does not run the forward kernel again.
 """
 
 from __future__ import annotations
@@ -30,6 +34,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -37,6 +42,7 @@ NEG_INF = -2.0 ** 30
 NT = (((1,), (1,)), ((), ()))        # contract the last dims: a @ b.T
 TN = (((0,), (0,)), ((), ()))        # contract the first dims: a.T @ b
 VMEM_LIMIT = 64 * 1024 * 1024        # of the v5e's 128 MiB
+SAVED = ("flash_o", "flash_lse")     # the forward's o and lse, by name
 
 
 def padded_len(S: int) -> int:
@@ -293,6 +299,7 @@ def _flash(q, k, v, scale, causal, kv_len, interpret):
 def _flash_fwd(q, k, v, scale, causal, kv_len, interpret):
     o, lse = _forward(q, k, v, scale=scale, causal=causal, kv_len=kv_len,
                       interpret=interpret)
+    o, lse = checkpoint_name(o, SAVED[0]), checkpoint_name(lse, SAVED[1])
     return o, (q, k, v, o, lse)
 
 

@@ -8,7 +8,9 @@ This module makes the one choice of backend for the whole program
     together), or ``None`` where it runs;
   * the kernel entry itself, which takes the model's own layout, brings
     it to the kernel's, and runs per shard under ``shard_map`` on a mesh
-    of more than one device.
+    of more than one device;
+  * ``REMAT_SAVED``: the names of the kernels' outputs that the layers'
+    remat keeps for the backward.
 
 The models keep their XLA paths and ask for the reason first.  The kernels
 run through the Pallas interpreter on the CPU backend (``_interpret``) and
@@ -29,6 +31,10 @@ from repro.sharding import kernel_spec
 from . import causal_conv as _cc
 from . import flash_attention as _fa
 from . import ssd_chunk as _sc
+
+
+# the flash forward's o and lse: kept, the backward does not run it again
+REMAT_SAVED = _fa.SAVED
 
 
 def on_tpu() -> bool:

@@ -124,7 +124,9 @@ def attend(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
     """Training/prefill attention, q: (B, Sq, H, hd), k/v: (B, Sk, K, hd):
     the Pallas flash kernel where the backend and shapes allow it, else
     ``chunked_attention``.  The choice is made, and recorded as
-    ``attn.dispatch``, when the caller is traced."""
+    ``attn.dispatch``, when the caller is traced; on the kernel's path the
+    record's ``saved`` lists the names the kernel gives its o and lse for
+    the remat to keep (``ops.REMAT_SAVED``)."""
     reason = ops.flash_attention_reason(q, k, v, window=window,
                                         q_offset=q_offset)
     attrs = {"path": "scan" if reason else "pallas",
@@ -132,6 +134,8 @@ def attend(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
              "causal": causal}
     if reason:
         attrs["reason"] = reason
+    else:
+        attrs["saved"] = list(ops.REMAT_SAVED)
     with obs.span("attn.dispatch", **attrs):
         pass
     if reason:

@@ -85,7 +85,9 @@ class ModelConfig:
     serve_layout: str = "v2"      # decode/prefill layout (weight-stationary TP)
     serve_fsdp: bool = True       # False: replicate weights over data when
                                   # they fit (kills per-token FSDP gathers)
-    remat: str = "full"           # none | full
+    remat: str = "full"           # none | full (keeps the layer input and
+                                  # the flash kernel's o, lse; transformer
+                                  # ._remat_wrap)
     scan_layers: bool = True
     attn_chunk: int = 1024
     loss_chunk: int = 2048

@@ -16,6 +16,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops
 from repro.sharding import shard, mesh_axis_sizes
 from .paramdecl import SpecLeaf, split_keys, stacked_init
 from .layers import (rmsnorm_init, rmsnorm, layernorm_init, layernorm,
@@ -449,11 +450,21 @@ def run_stack_prefill(cfg, stacked: Params, x: jax.Array, prefill_fn,
 
 # ------------------------------------------------------------ scan drivers
 def _remat_wrap(cfg, fn):
+    """``fn`` under the config's remat: ``"none"`` keeps every activation;
+    ``"full"`` keeps the layer's input and the outputs named in
+    ``ops.REMAT_SAVED`` and recomputes the rest in the backward.  Those
+    names exist only where attention runs the flash kernel (a TPU): there
+    a layer keeps its o (B, H, S, hd) in the compute dtype and lse
+    (B, H, S) in f32, about one more residual-stream activation (two at
+    hd 64, whose lanes the TPU pads to 128), so the backward does not run
+    the forward kernel again.  Elsewhere nothing is named and ``"full"``
+    keeps the layer's input alone."""
     if cfg.remat == "none":
         return fn
     if cfg.remat != "full":
         raise ValueError(f"remat={cfg.remat!r}: 'none' or 'full'")
-    return jax.checkpoint(fn)      # save nothing
+    return jax.checkpoint(fn, policy=jax.checkpoint_policies
+                          .save_only_these_names(*ops.REMAT_SAVED))
 
 
 def run_stack(cfg, stacked: Params, x: jax.Array, apply_fn,

@@ -2,6 +2,7 @@
 the choice ``attend`` makes through ``kernels/ops`` (interpret mode)."""
 
 import json
+import types
 
 import jax
 import jax.numpy as jnp
@@ -11,6 +12,7 @@ import pytest
 from repro import obs
 from repro.kernels import ops, ref
 from repro.models import attention
+from repro.models.transformer import _remat_wrap
 
 
 def _qkv(B, H, KH, S, D, dtype, seed=0):
@@ -149,8 +151,69 @@ def test_attend_runs_the_kernel_on_tpu(on_tpu, dispatch_records):
     got = _vjp(attention.attend, q, k, v, do)
     [rec] = dispatch_records()
     assert rec["path"] == "pallas" and "reason" not in rec
+    assert rec["saved"] == list(ops.REMAT_SAVED)
     want = _vjp(lambda q, k, v: attention.chunked_attention(q, k, v,
                                                             chunk=64),
                 q, k, v, do)
     for g, w in zip(got, want):
         np.testing.assert_allclose(g, w, atol=2e-5, rtol=1e-4)
+
+
+_FULL = types.SimpleNamespace(remat="full")
+
+
+def _layer_scan_grad(wrap):
+    """The gradient in the weights of a ``lax.scan`` over three layers of
+    ``wrap(block)``, where a block projects h (2, 64, 64) to q (4 heads of
+    16) and k, v (2 heads) and adds ``attend``'s output, projected, to h.
+    The block is made anew for each call, so no two share a trace."""
+    h = jax.random.normal(jax.random.PRNGKey(0), (2, 64, 64))
+
+    def block(h, w):
+        B, S, E = h.shape
+        q = (h @ w[0]).reshape(B, S, 4, 16)
+        k, v = ((h @ w[i])[..., :32].reshape(B, S, 2, 16) for i in (1, 2))
+        o = attention.attend(q, k, v).reshape(B, S, E)
+        return h + o @ w[3], None
+
+    def loss(ws):
+        return jnp.sum(jax.lax.scan(wrap(block), h, ws)[0] ** 2)
+    return jax.grad(loss)
+
+
+def _flash_forwards(jaxpr) -> int:
+    """How many flash forward kernels (the ``pallas_call``s with two
+    outputs, o and lse) the jaxpr holds, nested jaxprs included."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            n += len(eqn.outvars) == 2
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else (param,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _flash_forwards(sub)
+    return n
+
+
+def test_full_remat_keeps_the_flash_forward_outputs(on_tpu):
+    """On the kernel's path the ``"full"`` remat keeps the forward's o and
+    lse: the backward runs the forward kernel once, not twice as under a
+    plain ``jax.checkpoint``, and the gradients are the same bits."""
+    ws = jax.random.normal(jax.random.PRNGKey(1), (3, 4, 64, 64)) * 0.1
+    remat = lambda fn: _remat_wrap(_FULL, fn)  # noqa: E731
+    counts = [_flash_forwards(jax.make_jaxpr(_layer_scan_grad(w))(ws).jaxpr)
+              for w in (remat, jax.checkpoint)]
+    assert counts == [1, 2]
+    np.testing.assert_array_equal(_layer_scan_grad(remat)(ws),
+                                  _layer_scan_grad(jax.checkpoint)(ws))
+
+
+def test_full_remat_off_the_kernel_is_a_plain_checkpoint():
+    """Where attention runs the scan nothing is named, and the ``"full"``
+    remat lowers to the very program a plain ``jax.checkpoint`` does."""
+    ws = jax.ShapeDtypeStruct((3, 4, 64, 64), jnp.float32)
+    remat = lambda fn: _remat_wrap(_FULL, fn)  # noqa: E731
+    got, want = (jax.jit(_layer_scan_grad(w)).lower(ws).as_text()
+                 for w in (remat, jax.checkpoint))
+    assert got == want

@@ -6,10 +6,13 @@ memory.  These tests compile the Pallas kernels at the widths the main
 path runs them (the flash, SSD and conv kernels' backwards too) and the
 full-width ``tinyllama-1.1b`` training step of ``chip_smoke.py`` for one
 chip of a ``v5e:2x2`` topology, and training attention and the Mamba-2
-kernels on all four chips.
+kernels on all four chips, with and without the layers' ``"full"`` remat.
 """
 
+import contextlib
+import functools
 import os
+import types
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +25,7 @@ from repro.core.task import DEVICE_STREAM
 from repro.kernels import causal_conv, flash_attention, ops, ssd_chunk
 from repro.models.model import count_params
 from repro.models import attention
+from repro.models.transformer import _remat_wrap
 from repro.train import Trainer, TrainerConfig
 
 
@@ -51,6 +55,21 @@ def one_chip(topo):
 
 def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _kernel_calls(text: str) -> int:
+    return text.count('custom_call_target="tpu_custom_call"')
+
+
+def _four_chips(topo):
+    """The (data=4, model=1) mesh of the four chips, and its batch split."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    import numpy as np
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+    return mesh, NamedSharding(mesh, P("data"))
+
+
+_FULL = types.SimpleNamespace(remat="full")
 
 
 def _kernel_case(name, chip):
@@ -129,11 +148,8 @@ def test_training_attention_on_four_chips_gathers_no_activation(topo,
     """On a (data=4, model=1) mesh, the kernel runs per shard inside
     ``shard_map``: its forward and backward compile to TPU kernels and no
     all-gather of q, k, v or their gradients appears."""
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    import numpy as np
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
-    rows = NamedSharding(mesh, P("data"))
+    mesh, rows = _four_chips(topo)
     bf16 = jnp.bfloat16
     q = _sds((8, 1024, 12, 64), bf16, rows)
     kv = _sds((8, 1024, 4, 64), bf16, rows)
@@ -145,7 +161,7 @@ def test_training_attention_on_four_chips_gathers_no_activation(topo,
     with jax.set_mesh(mesh):
         text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
             q, kv, kv).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert _kernel_calls(text) == 2
     assert " all-gather" not in text and "all-gather-start" not in text
 
 
@@ -154,11 +170,9 @@ def test_chunked_ssd_on_four_chips_gathers_no_activation(topo, monkeypatch):
     their kernels per batch shard inside ``shard_map``: the SSD's three
     forward and three backward calls and the conv's two compile to TPU
     kernels and no all-gather of an operand or a gradient appears."""
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
-    rows = NamedSharding(mesh, P("data"))
+    mesh, rows = _four_chips(topo)
     whole = NamedSharding(mesh, P())
     bf16, f32 = jnp.bfloat16, jnp.float32
     args = [_sds((8, 1024, 16 * 64), bf16, rows),
@@ -175,5 +189,65 @@ def test_chunked_ssd_on_four_chips_gathers_no_activation(topo, monkeypatch):
     with jax.set_mesh(mesh):
         text = jax.jit(jax.grad(loss, argnums=tuple(range(8)))).lower(
             *args).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 8
+    assert _kernel_calls(text) == 8
     assert " all-gather" not in text and "all-gather-start" not in text
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_full_remat_runs_the_flash_forward_once(topo, one_chip, monkeypatch,
+                                                chips):
+    """At the ``internvl2-1b.train`` cell's widths (4096 positions, 14 q /
+    2 kv heads of 64; batch 3 on one chip, one row a chip on four through
+    ``shard_map``), the gradient of a ``"full"``-remat ``attend`` whose
+    output the backward reads, as a layer's is, compiles to the forward and
+    the backward kernel: the remat keeps o and lse.  A plain
+    ``jax.checkpoint`` compiles the forward a second time."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if chips == 1:
+        mesh, rows, B = None, one_chip, 3
+    else:
+        (mesh, rows), B = _four_chips(topo), 4
+    bf16 = jnp.bfloat16
+    q = _sds((B, 4096, 14, 64), bf16, rows)
+    kv = _sds((B, 4096, 2, 64), bf16, rows)
+
+    def calls(wrap):
+        attend = wrap(lambda q, k, v: attention.attend(q, k, v))
+
+        def loss(q, k, v):
+            return jnp.sum(attend(q, k, v).astype(jnp.float32) ** 2)
+        grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+        with jax.set_mesh(mesh) if mesh else contextlib.nullcontext():
+            return _kernel_calls(grad.lower(q, kv, kv).compile().as_text())
+    assert calls(lambda fn: _remat_wrap(_FULL, fn)) == 2
+    if chips == 1:
+        assert calls(jax.checkpoint) == 3
+
+
+def test_full_remat_keeps_nothing_of_the_ssd(one_chip, monkeypatch):
+    """A ``"full"``-remat block of ``ops.conv_silu`` and ``ops.ssd`` names
+    nothing for the remat to keep: its gradient compiles the same kernel
+    calls as under a plain ``jax.checkpoint``, the remat's second run of
+    the forward kernels included, which the block without remat has not."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    args = [_sds((2, 1024, 16 * 64), bf16, one_chip),
+            _sds((2, 1024, 128), bf16, one_chip),
+            _sds((2, 1024, 128), bf16, one_chip),
+            _sds((2, 1024, 16), f32, one_chip),
+            _sds((2, 1024, 16), f32, one_chip), _sds((16,), f32, one_chip),
+            _sds((4, 16 * 64), bf16, one_chip),
+            _sds((16 * 64,), bf16, one_chip)]
+
+    def block(x, b, c, dt, da, d, w, wb):
+        x = ops.conv_silu(x, w, wb).reshape(2, 1024, 16, 64)
+        return ops.ssd(d, x, b, c, dt, da, 256)
+
+    def calls(wrap):
+        def loss(*args):
+            state, y = wrap(block)(*args)
+            return jnp.sum(y.astype(f32) ** 2) + jnp.sum(state ** 2)
+        return _kernel_calls(jax.jit(jax.grad(
+            loss, argnums=tuple(range(8)))).lower(*args).compile().as_text())
+    full = calls(functools.partial(_remat_wrap, _FULL))
+    assert full == calls(jax.checkpoint) > calls(lambda fn: fn)
